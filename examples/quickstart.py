@@ -6,9 +6,11 @@ import numpy as np
 
 from repro.core.engine import SearchSpec, SearchStats, VectorSearchEngine
 from repro.data.synthetic import ground_truth, make_dataset, recall_at_k
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # 50K skewed vectors, 128-dim (SIFT-like per the paper's taxonomy)
     X, Q = make_dataset(50_000, 128, "skewed", n_queries=8, seed=0)
     gt_ids, gt_d = ground_truth(X, Q, k=10)

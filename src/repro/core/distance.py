@@ -109,7 +109,9 @@ def batched_distance_matmul(
     """(D, V), (B, D) -> (B, V) for l2/ip."""
     if metric == "l1":
         return jax.vmap(lambda q: pdx_distance(T, q, "l1"))(Q)
-    cross = Q @ T  # (B, V) — MXU
+    # HIGHEST: these are the exact distances; the TPU default rounds f32
+    # matmul operands to bf16
+    cross = jnp.matmul(Q, T, precision=jax.lax.Precision.HIGHEST)
     if metric == "ip":
         return -cross
     qn = jnp.sum(Q * Q, axis=1, keepdims=True)  # (B, 1)

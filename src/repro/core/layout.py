@@ -161,15 +161,17 @@ def _quantize_int4(data, ids, means):
     absmax = jnp.max(jnp.where(live, dev, 0.0), axis=(0, 2))
     scale = jnp.maximum(absmax, 1e-6) / 7.0
     offset = means
-    q = jnp.clip(
-        jnp.round((data - offset[None, :, None]) / scale[None, :, None]),
-        -7, 7,
-    ).astype(jnp.int32)
-    if q.shape[1] % 2:
-        q = jnp.pad(q, ((0, 0), (0, 1), (0, 0)))  # zero level -> nibble 8
+    return _quantize_extent_int4(data, scale, offset), scale, offset
+
+
+def _pack_int4(q: jax.Array) -> jax.Array:
+    """(m, D, C) integral f32 levels in [-7, 7] -> (m, ceil(D/2), C) bytes.
+    The +8-biased nibbles are formed in 8 bits: an int32 copy of the levels
+    would be as large as the f32 tiles themselves."""
     qb = (q + 8).astype(jnp.uint8)
-    packed = qb[:, 0::2, :] | (qb[:, 1::2, :] << 4)
-    return packed, scale, offset
+    if qb.shape[1] % 2:  # zero level -> nibble 8
+        qb = jnp.pad(qb, ((0, 0), (0, 1), (0, 0)), constant_values=8)
+    return qb[:, 0::2, :] | (qb[:, 1::2, :] << 4)
 
 
 def unpack_int4(packed: jax.Array, dim_axis: int = 0,
@@ -323,8 +325,11 @@ def projection_mirror(store, rank: int, dtype: str = "f32") -> ProjectionMirror:
             cache[("comps", version)] = comps
         Cj = jnp.asarray(comps[:, :rank])  # (D, rank)
         data = store.data  # triggers the mutable store's lazy f32 sync
-        proj = jnp.einsum("dr,pdc->prc", Cj, data)
-        means = Cj.T @ jnp.asarray(store.dim_means, jnp.float32)  # (rank,)
+        hi = jax.lax.Precision.HIGHEST  # same f32 projection as the queries
+        proj = jnp.einsum("dr,pdc->prc", Cj, data, precision=hi)
+        means = jnp.matmul(
+            Cj.T, jnp.asarray(store.dim_means, jnp.float32), precision=hi
+        )  # (rank,)
         if dtype == "f32":
             mdata = proj
             scale = jnp.ones((rank,), jnp.float32)
@@ -563,14 +568,19 @@ def _quantize_extent_int8(x, scale, offset):
 
 @jax.jit
 def _quantize_extent_int4(x, scale, offset):
-    q = jnp.clip(
-        jnp.round((x - offset[None, :, None]) / scale[None, :, None]),
-        -7, 7,
-    ).astype(jnp.int32)
-    if q.shape[1] % 2:
-        q = jnp.pad(q, ((0, 0), (0, 1), (0, 0)))
-    qb = (q + 8).astype(jnp.uint8)
-    return qb[:, 0::2, :] | (qb[:, 1::2, :] << 4)
+    def tiles(t):
+        return _pack_int4(jnp.clip(
+            jnp.round((t - offset[None, :, None]) / scale[None, :, None]),
+            -7, 7,
+        ))
+
+    # a few tiles per step: packing along D makes XLA:TPU materialize its
+    # operands, which for the whole store would take gigabytes of HBM.  The
+    # step divides m, so the split is a free reshape, never a sliced copy.
+    m = x.shape[0]
+    step = next(s for s in (8, 4, 2, 1) if m % s == 0)
+    out = jax.lax.map(tiles, x.reshape(m // step, step, *x.shape[1:]))
+    return out.reshape(m, *out.shape[2:])
 
 
 def _locked(fn):

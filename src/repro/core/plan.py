@@ -527,11 +527,6 @@ def warm_shapes(
             H = jnp.full((store.head_capacity, D), 0.0, jnp.float32)
             Qt = _transform_batch(pruner, jnp.asarray(Qb))
             _head_distances(H, Qt, spec.metric)
-        if spec.cascade is not None:
-            # the cascade executors pick pow2 compaction / re-rank shapes
-            # from runtime survivor counts — compile the whole menu, not
-            # just the one path the warm batch took
-            _warm_cascade_menu(spec, store, pruner, b, _resolve_pallas(spec))
         out[b] = plan.executor
     return out
 
@@ -946,23 +941,37 @@ def _cascade_stage(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("rk", "k"))
-def _cascade_finish(master, ids_scan, qt, dists, alive, rk, k,
-                    start: TopK) -> TopK:
-    """Exact terminal stage: top-``rk`` surviving flat positions by their
-    last approximate stage distance, re-scored against the f32 masters,
-    merged with the exact START candidates."""
-    flat_d = jnp.where(alive, dists, jnp.inf).reshape(-1)
-    cand = topk_from_batch(
-        flat_d, jnp.arange(flat_d.shape[0], dtype=jnp.int32), rk
-    )
-    res = rerank_positions(
-        master, ids_scan, qt[None],
-        TopK(cand.dists[None], cand.ids[None]), k, "l2",
-    )
-    return topk_merge(
-        TopK(dists=res.dists[0], ids=res.ids[0]), start.dists, start.ids
-    )
+@functools.partial(jax.jit, static_argnames=("k",))
+def _cascade_finish(master, ids_scan, qt, alive, k, start: TopK) -> TopK:
+    """Exact terminal stage: every lane the keep tests spared is re-scored
+    in f32 against the master tiles and merged with the exact START
+    candidates.
+
+    The survivors of the (exact-safe, quantization-inflated) final keep
+    test are exactly the lanes that could still enter the top-k, so all of
+    them are re-scored: a top-rk cut by the last stage's noisy distances
+    silently drops true neighbours when int4's reordering radius exceeds
+    rerank_mult*k.  The loop walks only the partitions holding a survivor,
+    one (D, C) master tile per step, so one executable serves every
+    survivor count."""
+    P, D, C = master.shape
+    alive = alive.reshape(P, C)
+    has = jnp.any(alive, axis=1)
+    order = jnp.argsort(~has)  # stable: partitions with survivors first
+
+    def body(i, state):
+        p = order[i]
+        d = jnp.where(alive[p], pdx_distance(master[p], qt, "l2"), jnp.inf)
+        return topk_merge(state, d, ids_scan[p])
+
+    res = jax.lax.fori_loop(0, jnp.sum(has), body, topk_init(k))
+    return topk_merge(res, start.dists, start.ids)
+
+
+def _finish_partitions(alive, C: int) -> int:
+    """Partitions ``_cascade_finish`` re-scores for one query's survivor
+    bitmap (a host sync, taken only for the meters)."""
+    return int(np.asarray(alive).reshape(-1, C).any(axis=1).sum())
 
 
 @register_executor("cascade-scan")
@@ -992,7 +1001,6 @@ def _exec_cascade_scan(store, pruner, Q, spec, *, ivf, mesh, stats):
     ]
     use_pallas = _resolve_pallas(spec)
     P, C, D = store.num_partitions, store.capacity, store.dim
-    rk = min(spec.rerank_mult * spec.k, P * C)
     prune = pruner.name == "adsampling" and pruner.aux is not None
     eps0 = float(pruner.aux["eps0"]) if prune else 2.1
     qerrs = [_quant_err_norm(m) for m in mirrors]
@@ -1026,7 +1034,8 @@ def _exec_cascade_scan(store, pruner, Q, spec, *, ivf, mesh, stats):
                 # L2 lower-bounds the full L2 exactly (eps 0 — intermediate
                 # ADSampling-style scaled tests are unsafe on PCA-projected
                 # coordinates)
-                qs = qt @ mirror.components
+                qs = jnp.matmul(qt, mirror.components,
+                                precision=jax.lax.Precision.HIGHEST)
                 thr_i, eps_i, d_tile = thr_q, 0.0, rank
             else:
                 qs = qt
@@ -1078,20 +1087,11 @@ def _exec_cascade_scan(store, pruner, Q, spec, *, ivf, mesh, stats):
                         dtype=mirror.dtype,
                     )
                 lanes_in = n_surv
-        # the survivors of the (exact-safe, quantization-inflated) final
-        # keep test are EXACTLY the candidates that could still enter the
-        # top-k, so the re-rank must cover them all — a top-rk cut by the
-        # last stage's noisy distances silently drops true neighbours when
-        # int4's reordering radius exceeds rerank_mult*k.  rk widens to the
-        # survivor count, pow2-bucketed so jit specializations stay bounded.
-        n_alive = int(np.asarray((alive > 0).sum()))
-        rk_eff = rk
-        if n_alive > rk_eff:
-            rk_eff = min(1 << (n_alive - 1).bit_length(), P * C)
-        computed += float(rk_eff) * D
-        res = _cascade_finish(
-            store.data, ids_scan, qt, dists, alive, rk_eff, spec.k, start
+        res = _cascade_finish(store.data, ids_scan, qt, alive, spec.k, start)
+        rerank_values = (
+            _finish_partitions(alive, C) * C * D if meter else 0.0
         )
+        computed += rerank_values
         if stats is not None:
             total = float(counts.sum()) * D
             stats.values_total += total
@@ -1104,14 +1104,24 @@ def _exec_cascade_scan(store, pruner, Q, spec, *, ivf, mesh, stats):
                 executor="cascade-scan", component="start", dtype="f32",
             )
             _metrics.counter(
-                "repro_device_bytes_total", float(rk_eff * D * 4),
+                "repro_device_bytes_total", float(rerank_values * 4),
                 executor="cascade-scan", component="rerank", dtype="f32",
             )
         out_i.append(np.asarray(res.ids))
         out_d.append(np.asarray(res.dists))
-    with _trace.span("rerank", fused="in-kernel", rk=rk):
+    with _trace.span("rerank", fused="in-kernel"):
         pass
     return np.stack(out_i), np.stack(out_d)
+
+
+# Union-survivor columns per step of a batched cascade stage: at D = 1536
+# an int8 chunk is 25 MB, and the step count, not the shape, follows the
+# survivor count.
+_CASCADE_CHUNK = 16384
+
+
+def _stage_chunk(PC: int) -> int:
+    return min(_CASCADE_CHUNK, PC)
 
 
 @functools.partial(
@@ -1119,31 +1129,42 @@ def _exec_cascade_scan(store, pruner, Q, spec, *, ivf, mesh, stats):
     static_argnames=("eps0", "d_tile", "use_pallas", "packed", "dim"),
 )
 def _cascade_batch_stage(
-    mdata, idx, alive, qs, thr, scale, offset, eps0, d_tile, use_pallas,
-    packed, dim,
+    mdata, idx, n_chunks, alive, qs, thr, scale, offset, eps0, d_tile,
+    use_pallas, packed, dim,
 ):
     """One MXU-batched cascade stage: gather the union-survivor columns of
-    the (P, D_i, C) stage mirror into a compacted (D_i, S) tile, run the
-    batched d-tile keep-test ladder over the whole query batch, scatter
-    dists/alive back to flat (B, P*C) slot order (flat slot = p*C + c).
-    ``idx`` is the pow2-padded union-survivor index list; pad entries carry
-    P*C and land in a throwaway column that is sliced off."""
+    the (P, D_i, C) stage mirror chunk by chunk into compacted (D_i, W)
+    tiles, run the batched d-tile keep-test ladder over the whole query
+    batch, scatter dists/alive back to flat (B, P*C) slot order (flat slot
+    = p*C + c).  ``idx`` lists the union survivors first, padded with P*C
+    (a throwaway column that is sliced off) to whole chunks of
+    ``W = _stage_chunk(P*C)``; only the first ``n_chunks`` chunks run, a
+    traced count, so every survivor count shares one executable."""
     from ..kernels.ops import batched_cascade_stage_op
 
     P, Dp, C = mdata.shape
     PC = P * C
     B = alive.shape[0]
+    W = _stage_chunk(PC)
     flat = mdata.transpose(1, 0, 2).reshape(Dp, PC)
-    Tc = flat[:, jnp.minimum(idx, PC - 1)]
     alive_ext = jnp.concatenate(
         [alive, jnp.zeros((B, 1), alive.dtype)], axis=1
     )
-    d_c, a_c = batched_cascade_stage_op(
-        Tc, alive_ext[:, idx], qs, thr, scale, offset, eps0=eps0,
-        d_tile=d_tile, use_pallas=use_pallas, packed=packed, dim=dim,
-    )
-    d_full = jnp.zeros((B, PC + 1), jnp.float32).at[:, idx].set(d_c)
-    a_full = jnp.zeros((B, PC + 1), jnp.bool_).at[:, idx].set(a_c)
+
+    def chunk(c, acc):
+        d_full, a_full = acc
+        ic = jax.lax.dynamic_slice_in_dim(idx, c * W, W)
+        d_c, a_c = batched_cascade_stage_op(
+            flat[:, jnp.minimum(ic, PC - 1)], alive_ext[:, ic], qs, thr,
+            scale, offset, eps0=eps0, d_tile=d_tile, use_pallas=use_pallas,
+            packed=packed, dim=dim,
+        )
+        return d_full.at[:, ic].set(d_c), a_full.at[:, ic].set(a_c)
+
+    d_full, a_full = jax.lax.fori_loop(0, n_chunks, chunk, (
+        jnp.zeros((B, PC + 1), jnp.float32),
+        jnp.zeros((B, PC + 1), jnp.bool_),
+    ))
     return d_full[:, :PC], a_full[:, :PC]
 
 
@@ -1153,18 +1174,17 @@ def _exec_cascade_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
     runs ONCE over the whole query batch instead of once per query,
     carrying a shared (B, P*C) survivor bitmap between stages.
 
-    Per stage, the union of every query's survivors is compacted to a
-    pow2-bucketed column set (compiled shapes stay bounded), the stage
-    mirror's surviving columns are gathered once, and the d-tile ladder
-    runs through the batched quantized MXU kernel with per-query
+    Per stage, the union of every query's survivors is compacted into
+    fixed-width column chunks (one compiled shape for any survivor count),
+    the stage mirror's surviving columns are gathered once, and the d-tile
+    ladder runs through the batched quantized MXU kernel with per-query
     thresholds — a column fetched for any query is scanned for all B, so
     stage bytes are paid per batch, not per query.  START threshold
     seeding and the exact f32 re-rank stay per query with the same
     arithmetic as ``cascade-scan``: the final top-k depends only on the
-    survivor bitmap and the exact re-rank (the rk cut always covers every
-    survivor), both of which this executor reproduces, so ids match the
-    per-query path bitwise.  The planner keeps the host loop as the B=1
-    fallback."""
+    survivor bitmap and the exact re-rank of every survivor, both of which
+    this executor reproduces, so ids match the per-query path bitwise.
+    The planner keeps the host loop as the B=1 fallback."""
     if spec.metric != "l2":
         raise ValueError("cascade-batch is L2-only (spec validation "
                          "enforces this)")
@@ -1180,7 +1200,6 @@ def _exec_cascade_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
     P, C, D = store.num_partitions, store.capacity, store.dim
     PC = P * C
     B = Q.shape[0]
-    rk = min(spec.rerank_mult * spec.k, PC)
     prune = pruner.name == "adsampling" and pruner.aux is not None
     eps0 = float(pruner.aux["eps0"]) if prune else 2.1
     qerrs = [_quant_err_norm(m) for m in mirrors]
@@ -1215,25 +1234,26 @@ def _exec_cascade_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
     ):
         thr_q = (jnp.sqrt(thr) + qerrs[si]) ** 2
         if kind == "proj":
-            Qs = Qt @ mirror.components
+            Qs = jnp.matmul(Qt, mirror.components,
+                            precision=jax.lax.Precision.HIGHEST)
             thr_i, eps_i, d_tile = thr_q, 0.0, rank
         else:
             Qs = Qt
             thr_i = thr_q if prune else jnp.full((B,), np.inf, jnp.float32)
             eps_i, d_tile = eps0, 64
-        # host-synced union count -> pow2-bucketed compacted shape
-        union = np.asarray(jnp.any(alive, axis=0))
-        S = pow2_bucket(max(int(union.sum()), 1), PC)
-        nz = np.flatnonzero(union)
-        idx_np = np.full((S,), PC, np.int32)
+        # host-synced union -> column chunks of a fixed width
+        nz = np.flatnonzero(np.asarray(jnp.any(alive, axis=0)))
+        width = _stage_chunk(PC)
+        n_chunks = max(-(-nz.size // width), 1)
+        idx_np = np.full((-(-PC // width) * width,), PC, np.int32)
         idx_np[: nz.size] = nz
-        idx = jnp.asarray(idx_np)
         sc = mirror.scale if mirror.quantized else None
         off = mirror.offset if mirror.quantized else None
         dists, alive = _cascade_batch_stage(
-            mirror.data, idx, alive, Qs, thr_i, sc, off, eps_i, d_tile,
-            use_pallas, mirror.packed, mirror.dim,
+            mirror.data, jnp.asarray(idx_np), n_chunks, alive, Qs, thr_i,
+            sc, off, eps_i, d_tile, use_pallas, mirror.packed, mirror.dim,
         )
+        S = n_chunks * width  # columns gathered, chunk padding included
         if meter:
             surv_b = np.asarray(jnp.sum(alive, axis=1)).astype(np.float64)
             # realized traffic: the compacted union columns are gathered
@@ -1257,28 +1277,25 @@ def _exec_cascade_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
                     dtype=mirror.dtype,
                 )
             lanes_in = surv_b
-    # exact per-query finish: rk widens to the survivor count so the
-    # re-rank covers every lane the keep tests spared (see cascade-scan)
-    n_alive_b = np.asarray(jnp.sum(alive, axis=1))
+    # exact per-query finish over every lane the keep tests spared
+    # (see _cascade_finish)
     out_i, out_d = [], []
     for b in range(B):
-        n_alive = int(n_alive_b[b])
-        rk_eff = rk
-        if n_alive > rk_eff:
-            rk_eff = min(1 << (n_alive - 1).bit_length(), PC)
         ids_scan = store.ids.at[p0s[b]].set(-1)
         res = _cascade_finish(
-            store.data, ids_scan, qts[b], dists[b], alive[b], rk_eff,
-            spec.k, starts[b],
+            store.data, ids_scan, qts[b], alive[b], spec.k, starts[b],
         )
-        computed[b] += float(rk_eff) * D
+        rerank_values = (
+            _finish_partitions(alive[b], C) * C * D if meter else 0.0
+        )
+        computed[b] += rerank_values
         if _metrics.enabled():
             _metrics.counter(
                 "repro_device_bytes_total", float(D * C * 4),
                 executor="cascade-batch", component="start", dtype="f32",
             )
             _metrics.counter(
-                "repro_device_bytes_total", float(rk_eff * D * 4),
+                "repro_device_bytes_total", float(rerank_values * 4),
                 executor="cascade-batch", component="rerank", dtype="f32",
             )
         out_i.append(np.asarray(res.ids))
@@ -1289,69 +1306,9 @@ def _exec_cascade_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
         stats.values_computed += float(computed.sum())
         stats.values_avoided += max(total * B - float(computed.sum()), 0.0)
         stats.partitions_visited += P * B
-    with _trace.span("rerank", fused="in-kernel", rk=rk):
+    with _trace.span("rerank", fused="in-kernel"):
         pass
     return np.stack(out_i), np.stack(out_d)
-
-
-def _warm_cascade_menu(spec, store, pruner, B: int, use_pallas: bool) -> None:
-    """Pre-compile the cascade executors' data-dependent shape menus for
-    batch shape ``B``: every pow2 survivor-compaction width ``S`` the
-    batched stage gather can request, and every pow2-widened re-rank
-    ``rk_eff`` the finish can request.  One real warm batch only seeds the
-    shapes its own survivor counts happen to hit; a serving steady state
-    must mint no executables for ANY survivor profile, so the whole menu
-    compiles up front (it is log2(P*C)-bounded per stage)."""
-    scan_stages = [parse_cascade_stage(s) for s in spec.cascade][:-1]
-    mirrors = [
-        projection_mirror(store, rank, dt) if kind == "proj"
-        else device_mirror(store, dt)
-        for kind, dt, rank in scan_stages
-    ]
-    P, C, D = store.num_partitions, store.capacity, store.dim
-    PC = P * C
-    prune = pruner.name == "adsampling" and pruner.aux is not None
-    eps0 = float(pruner.aux["eps0"]) if prune else 2.1
-    menu = []
-    s = 1
-    while s < PC:
-        menu.append(s)
-        s *= 2
-    menu.append(PC)
-    qt0 = pruner.transform_query(jnp.zeros((D,), jnp.float32))
-    start = topk_from_batch(
-        pdx_distance(store.data[0], qt0, "l2"), store.ids[0], spec.k
-    )
-    if B > 1:  # the B=1 fallback never compacts batched stages
-        alive0 = jnp.zeros((B, PC), jnp.bool_)
-        thr0 = jnp.zeros((B,), jnp.float32)
-        for (kind, dt, rank), mirror in zip(scan_stages, mirrors):
-            Qs = jnp.zeros((B, rank if kind == "proj" else D), jnp.float32)
-            eps_i = 0.0 if kind == "proj" else eps0
-            d_tile = rank if kind == "proj" else 64
-            sc = mirror.scale if mirror.quantized else None
-            off = mirror.offset if mirror.quantized else None
-            for S in menu:
-                idx = jnp.full((S,), PC, jnp.int32)
-                _cascade_batch_stage(
-                    mirror.data, idx, alive0, Qs, thr0, sc, off, eps_i,
-                    d_tile, use_pallas, mirror.packed, mirror.dim,
-                )
-    rk = min(spec.rerank_mult * spec.k, PC)
-    rks = {rk}
-    p = 1
-    while p < PC:
-        if p > rk:
-            rks.add(p)
-        p *= 2
-    if PC > rk:
-        rks.add(PC)  # the widened cut caps at PC (PC need not be pow2)
-    if B > 1:
-        dd, aa = jnp.zeros((PC,), jnp.float32), jnp.zeros((PC,), jnp.bool_)
-    else:
-        dd, aa = jnp.zeros((P, C), jnp.float32), jnp.zeros((P, C), jnp.bool_)
-    for r in sorted(rks):
-        _cascade_finish(store.data, store.ids, qt0, dd, aa, r, spec.k, start)
 
 
 def _get_placement(store, n_shards: int, kind: str, *, ivf=None, axis="data"):
@@ -2044,15 +2001,14 @@ def _tiered_shard_exec(mesh, axis: str, rk: int, metric: str,
         S, _, C = pool.shape
         pos = jnp.arange(S * C, dtype=jnp.int32).reshape(S, C)
         pos = jnp.where(ids_dev >= 0, pos, -1)
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(), P(), P(), P()),
             out_specs=TopK(dists=P(), ids=P()),
-            check_rep=False,
+            check_vma=False,
         )(pool, pos, slot_bucket, sel, Qt, scale, offset)
 
     fn = jax.jit(wrapper)

@@ -142,7 +142,9 @@ def make_adsampling(dim: int, eps0: float = 2.1, seed: int = 0) -> Pruner:
         is_exact=False,
         needs_preprocess=True,
         preprocess=lambda X: (np.asarray(X, np.float32) @ P.T),
-        transform_query=lambda q: Pj @ q,
+        transform_query=lambda q: jnp.matmul(
+            Pj, q, precision=jax.lax.Precision.HIGHEST
+        ),
         keep_mask=keep_mask,
         fingerprint=pruner_fingerprint("adsampling", dim, eps0, seed),
         # the fused Pallas scan executors bake the hypothesis test into the
@@ -209,7 +211,9 @@ def make_bsa(X_sample: np.ndarray, m: float = 3.0, seed: int = 0) -> Pruner:
         is_exact=False,
         needs_preprocess=True,
         preprocess=lambda X: (np.asarray(X, np.float32) @ components),
-        transform_query=lambda q: q @ Cj,
+        transform_query=lambda q: jnp.matmul(
+            q, Cj, precision=jax.lax.Precision.HIGHEST
+        ),
         keep_mask=keep_mask,
         fingerprint=pruner_fingerprint("bsa", components, m),
         aux={"components": components, "m": m, "seed": seed},

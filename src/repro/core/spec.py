@@ -115,7 +115,7 @@ class SearchSpec:
                     either breaks exact k-boundary ordering — see
                     ``repro.dist.routing``).
       kernel      — scan implementation: "pallas" forces the fused Pallas
-                    executors (``repro.kernels``; interpret mode off-TPU),
+                    executors (``repro.kernels``; interpreted on the CPU),
                     "jnp" forces the XLA-fused jnp bodies, "auto" picks
                     pallas on a TPU backend and jnp elsewhere.
       rerank_mult — exact-re-rank candidate multiplier (top ``rerank_mult *

@@ -25,6 +25,13 @@ __all__ = [
 
 INF = jnp.float32(jnp.inf)
 
+# Candidate batches longer than this are pre-reduced row by row.
+_TOPK_ROW = 4096
+
+# Candidates re-scored per step of ``rerank_positions``: each reads one
+# (D, 128) tile row, so a step holds _RERANK_BLOCK * D * 512 bytes.
+_RERANK_BLOCK = 256
+
 
 class TopK(NamedTuple):
     dists: jax.Array  # (k,) ascending
@@ -42,6 +49,20 @@ def topk_merge(state: TopK, cand_dists: jax.Array, cand_ids: jax.Array) -> TopK:
     k = state.dists.shape[0]
     # Guard: candidates with id == -1 are padding slots from partial tiles.
     cand_dists = jnp.where(cand_ids < 0, INF, cand_dists)
+    m = cand_dists.shape[0]
+    if m > _TOPK_ROW and k < _TOPK_ROW:
+        # Exact two-level selection: a candidate in the top k is in the top
+        # k of its row (ties go to the lower index at both levels, and rows
+        # keep index order), so the result equals one top_k over all m.
+        # The TPU compiler takes tens of seconds on one top_k over ~1e6.
+        pad = (-m) % _TOPK_ROW
+        d2 = jnp.pad(cand_dists, (0, pad), constant_values=INF)
+        i2 = jnp.pad(cand_ids, (0, pad), constant_values=-1)
+        neg, pos = jax.lax.top_k(-d2.reshape(-1, _TOPK_ROW), k)
+        cand_dists = -neg.reshape(-1)
+        cand_ids = jnp.take_along_axis(
+            i2.reshape(-1, _TOPK_ROW), pos, axis=1
+        ).reshape(-1)
     all_d = jnp.concatenate([state.dists, cand_dists])
     all_i = jnp.concatenate([state.ids, cand_ids])
     neg_top, idx = jax.lax.top_k(-all_d, k)
@@ -75,8 +96,25 @@ def rerank_positions(
 
     P, D, C = master.shape
     safe = jnp.maximum(cand.ids, 0)                      # (B, rk) positions
-    vecs = master[safe // C, :, safe % C]                # (B, rk, D) f32
-    d = jax.vmap(lambda V_, q_: nary_distance(V_, q_, metric))(vecs, Q)
+    B, rk = safe.shape
+    # A candidate's column is D values strided by C.  Gathering those
+    # directly makes XLA:TPU first relayout all of ``master`` with D minor,
+    # a copy the size of the store on every call; slicing the candidate's
+    # whole 128-lane tile row and picking its lane reads only that row.
+    L = 128 if C % 128 == 0 else C
+
+    def column_distance(pos, b):
+        p, c = pos // C, pos % C
+        row = jax.lax.dynamic_slice(master, (p, 0, c - c % L), (1, D, L))
+        col = jax.lax.dynamic_index_in_dim(row[0], c % L, axis=1)  # (D, 1)
+        q = jax.lax.dynamic_index_in_dim(Q, b, keepdims=False)
+        return nary_distance(col.T, q, metric)[0]
+
+    d = jax.lax.map(
+        lambda pb: column_distance(*pb),
+        (safe.reshape(-1), jnp.repeat(jnp.arange(B), rk)),
+        batch_size=min(B * rk, _RERANK_BLOCK),
+    ).reshape(B, rk)
     d = jnp.where(cand.ids >= 0, d, INF)
     gids = jnp.where(cand.ids >= 0, ids.reshape(-1)[safe], -1)
     merge = lambda dd, ii: topk_merge(topk_init(k), dd, ii)  # noqa: E731

@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.pdxearch import (
@@ -160,7 +160,7 @@ def search_block_sharded(
         mesh=mesh,
         in_specs=(P(axis), P(axis), P()),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(data, ids, q)
     if not with_stats:
@@ -210,7 +210,7 @@ def search_dim_sharded(
         mesh=mesh,
         in_specs=(P(None, axis), P(axis)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(data, q.astype(jnp.float32))
     return topk_merge(topk_init(k), dmat.reshape(-1), ids.reshape(-1))
 
@@ -275,7 +275,7 @@ def search_batch_block_sharded(
             mesh=mesh,
             in_specs=(P(axis), P(axis), P()),
             out_specs=TopK(dists=P(), ids=P()),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(data, ids, Q.astype(jnp.float32))
 
@@ -328,7 +328,7 @@ def search_batch_block_sharded(
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P()),
         out_specs=TopK(dists=P(), ids=P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(data, ids, qtiles, Q.astype(jnp.float32))
 

@@ -60,7 +60,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.distance import batched_distance_matmul
@@ -329,7 +329,7 @@ def _routed_exec(mesh, axis: str, D: int, nprobe: int, k: int, metric: str,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(), P(), P(),
                   P(axis), P(), P()),
         out_specs=TopK(dists=P(), ids=P()),
-        check_rep=False,
+        check_vma=False,
     ))
     _ROUTED_CACHE[key] = fn
     while len(_ROUTED_CACHE) > _ROUTED_CACHE_MAX:

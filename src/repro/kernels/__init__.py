@@ -3,7 +3,8 @@
 <name>.py hold pl.pallas_call kernels with explicit BlockSpec VMEM tiling;
 ops.py are the jit'd public wrappers (padding, tile selection, the
 pallas-vs-jnp body knob); ref.py are the pure-jnp oracles every kernel is
-tested against (interpret=True on CPU).
+tested against.  _backend.py says how they run: compiled on a TPU,
+interpreted on the CPU (the correctness gate), refused anywhere else.
 
 Design notes
 ============
@@ -26,10 +27,16 @@ state (accumulator + keep-mask) never round-trips to HBM.
 ``(d_tile, V)`` accumulation the keep-mask is re-evaluated in place, and a
 ``pl.when(any_alive)`` guard skips the *entire* remaining VPU work of a
 partition once every lane is dead (the PRUNE phase at tile granularity).
-The HBM->VMEM fetch of later tiles still streams under the automatic
-pipeline; hoisting it needs manual DMA with scalar prefetch
-(``PrefetchScalarGridSpec``) and is deliberately out of scope while the
-planner's unit of skip is the partition.
+In ``pdx_prune_scan_multi_pallas`` the HBM->VMEM fetch of later tiles still
+streams under the automatic pipeline; the later cascade stages run
+``pdx_prune_scan_multi_prefetch_pallas``, whose scalar-prefetched
+(partition, d-tile) schedule and conditional manual DMA stop a partition's
+fetches at the d-tile where its last lane dies.
+
+**TPU tiling.**  A block's last two dims must be multiples of (8, 128) or
+equal the array's.  Per-partition rows (ids in; dists, alive, streamed out)
+therefore travel as ``(P, 1, V)`` with the partition axis squeezed, never as
+``(1, V)`` blocks of a ``(P, V)`` array.
 
 **Quantized mirrors.**  The scan is bandwidth-bound (paper Section 7), so
 the executors stream reduced-precision device mirrors (bf16/int8, see

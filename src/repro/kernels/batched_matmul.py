@@ -14,11 +14,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import _backend
+
 __all__ = ["batched_distance_pallas", "batched_distance_quant_pallas"]
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# The distance is ||q||^2 - 2 q.x + ||x||^2: at the TPU's default precision
+# (one bf16 pass) the cross term's error swamps the neighbour gaps of
+# 1536-wide embeddings, even as a pre-filter before an exact re-rank.
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _bmm_kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, *, nd: int, metric: str):
@@ -31,7 +34,9 @@ def _bmm_kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, *, nd: int, metric: str):
     q = q_ref[...].astype(jnp.float32)  # (bt, dt)
     x = x_ref[...].astype(jnp.float32)  # (dt, vt)
     cross = jax.lax.dot_general(
-        q, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (0,)), ((), ())),
+        precision=_EXACT,
+        preferred_element_type=jnp.float32,
     )
     if metric == "ip":
         o_ref[...] += -cross
@@ -78,7 +83,7 @@ def batched_distance_pallas(
         ],
         out_specs=pl.BlockSpec((b_tile, v_tile), lambda b, v, i: (b, v)),
         out_shape=jax.ShapeDtypeStruct((B, V), jnp.float32),
-        interpret=_interpret(),
+        interpret=_backend.interpret_mode(),
     )(Q, T, qn, xn)
     return out
 
@@ -104,7 +109,9 @@ def _bmm_quant_kernel(
         x = x * scale_ref[...] + offset_ref[...]
     q = q_ref[...].astype(jnp.float32)  # (bt, dt)
     cross = jax.lax.dot_general(
-        q, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (0,)), ((), ())),
+        precision=_EXACT,
+        preferred_element_type=jnp.float32,
     )
     if metric == "ip":
         o_ref[...] += -cross
@@ -155,6 +162,6 @@ def batched_distance_quant_pallas(
         ],
         out_specs=pl.BlockSpec((b_tile, v_tile), lambda b, v, i: (b, v)),
         out_shape=jax.ShapeDtypeStruct((B, V), jnp.float32),
-        interpret=_interpret(),
+        interpret=_backend.interpret_mode(),
     )(Q32, T, qn, scale.reshape(D, 1), offset.reshape(D, 1))
     return out

@@ -12,11 +12,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import _backend
+
 __all__ = ["nary_distance_pallas"]
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _nary_kernel(q_ref, x_ref, o_ref, *, metric: str):
@@ -61,6 +59,6 @@ def nary_distance_pallas(
         ],
         out_specs=pl.BlockSpec((n_tile, 1), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
-        interpret=_interpret(),
+        interpret=_backend.interpret_mode(),
     )(q2, X)
     return out[:, 0]

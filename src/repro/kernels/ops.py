@@ -2,7 +2,7 @@
 tile-size selection.  Callers use these; the raw kernels stay minimal.
 
 Every executor-facing op takes a static ``use_pallas`` knob: True runs the
-Pallas kernel (interpret mode off-TPU — the correctness gate), False runs
+Pallas kernel (interpreted on the CPU — the correctness gate), False runs
 the pure-jnp oracle body from ``ref`` under the same contract (the XLA
 fallback the planner picks via ``SearchSpec.kernel="jnp"``).
 """
@@ -41,13 +41,6 @@ __all__ = [
 # 0x88 decodes to the (0, 0) level pair, which dequantizes to 0 under the
 # zero-padded scale/offset — exactly like the 0 padding of unpacked tiles.
 _INT4_PAD_BYTE = 0x88
-
-
-def _unpack_int4_levels(T: jax.Array, dim: int) -> jax.Array:
-    """(Dp, ...) packed bytes -> (dim, ...) int8 quantization levels."""
-    p = T.astype(jnp.int32)
-    full = jnp.stack([(p & 0xF) - 8, (p >> 4) - 8], axis=1)
-    return full.reshape((2 * T.shape[0],) + T.shape[1:])[:dim].astype(jnp.int8)
 
 
 def _pad_to(
@@ -295,7 +288,7 @@ def batched_distance_quant_op(
     levels outside the kernel (XLA fuses the unpack into the feed) and the
     existing quantized MXU path runs unchanged."""
     if packed:
-        T = _unpack_int4_levels(T, dim)
+        T = ref.unpack_int4_ref(T, 0, dim)
     if not use_pallas:
         return ref.batched_distance_quant_ref(T, Q, scale, offset, metric)
     D, V = T.shape
@@ -348,7 +341,7 @@ def batched_cascade_stage_op(
     ``packed`` int4 columns unpack to int8 levels once up front; per-tile
     scale/offset slices ride into the kernel's in-register dequant."""
     if packed:
-        T = _unpack_int4_levels(T, dim)
+        T = ref.unpack_int4_ref(T, 0, dim)
     D = T.shape[0]
     quantized = scale is not None
     a = alive.astype(jnp.float32)

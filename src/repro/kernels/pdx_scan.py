@@ -29,16 +29,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import _backend
+
 __all__ = [
     "pdx_distance_pallas",
     "pdx_prune_scan_pallas",
     "pdx_prune_scan_multi_pallas",
     "pdx_prune_scan_multi_prefetch_pallas",
 ]
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # --------------------------------------------------------------------------
@@ -87,7 +85,7 @@ def pdx_distance_pallas(
         ],
         out_specs=pl.BlockSpec((1, v_tile), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, V), jnp.float32),
-        interpret=_interpret(),
+        interpret=_backend.interpret_mode(),
     )(q2, T)
     return out[0]
 
@@ -176,7 +174,7 @@ def pdx_prune_scan_pallas(
             jax.ShapeDtypeStruct((1, V), jnp.float32),
             jax.ShapeDtypeStruct((1, V), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_backend.interpret_mode(),
     )(q2, T, ids2, thr2)
     return dists[0], alive[0]
 
@@ -207,14 +205,14 @@ def _prune_scan_multi_kernel(
             # int4 in-register unpack: the byte block (dt/2, V) holds the
             # even dim in its low nibble, the odd dim in its high nibble,
             # +8 biased.  Interleave back to (dt, V) quantization levels.
-            xi = x_ref[0].astype(jnp.int32)                  # (dt/2, V)
+            xi = x_ref[...].astype(jnp.int32)                # (dt/2, V)
             lo = (xi & 0xF) - 8
             hi = (xi >> 4) - 8
             x = jnp.stack([lo, hi], axis=1).reshape(
                 2 * xi.shape[0], xi.shape[1]
             ).astype(jnp.float32)
         else:
-            x = x_ref[0].astype(jnp.float32)                 # (dt, V)
+            x = x_ref[...].astype(jnp.float32)               # (dt, V)
         if quantized:
             # in-register dequantization: the f32 value never touches HBM
             x = x * scale_ref[...] + offset_ref[...]
@@ -269,7 +267,10 @@ def pdx_prune_scan_multi_pallas(
     thr2 = jnp.asarray(thr, jnp.float32).reshape(1, 1)
     scale2 = scale.reshape(D, 1)
     offset2 = offset.reshape(D, 1)
-    x_block = (1, d_tile // 2, V) if packed else (1, d_tile, V)
+    x_block = (pl.squeezed, d_tile // 2 if packed else d_tile, V)
+    # (P, V) rows travel as (P, 1, V) with the partition axis squeezed, so
+    # each block's last two dims equal the array's (the TPU tiling rule)
+    row = pl.BlockSpec((pl.squeezed, 1, V), lambda p, i: (p, 0, 0))
     grid = (P, nd)
     dists, alive = pl.pallas_call(
         functools.partial(
@@ -280,22 +281,19 @@ def pdx_prune_scan_multi_pallas(
         in_specs=[
             pl.BlockSpec((d_tile, 1), lambda p, i: (i, 0)),
             pl.BlockSpec(x_block, lambda p, i: (p, i, 0)),
-            pl.BlockSpec((1, V), lambda p, i: (p, 0)),
+            row,
             pl.BlockSpec((1, 1), lambda p, i: (0, 0)),
             pl.BlockSpec((d_tile, 1), lambda p, i: (i, 0)),
             pl.BlockSpec((d_tile, 1), lambda p, i: (i, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, V), lambda p, i: (p, 0)),
-            pl.BlockSpec((1, V), lambda p, i: (p, 0)),
-        ],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((P, V), jnp.float32),
-            jax.ShapeDtypeStruct((P, V), jnp.float32),
+            jax.ShapeDtypeStruct((P, 1, V), jnp.float32),
+            jax.ShapeDtypeStruct((P, 1, V), jnp.float32),
         ],
-        interpret=_interpret(),
-    )(q2, T, ids, thr2, scale2, offset2)
-    return dists, alive
+        interpret=_backend.interpret_mode(),
+    )(q2, T, ids.reshape(P, 1, V), thr2, scale2, offset2)
+    return dists.reshape(P, V), alive.reshape(P, V)
 
 
 # --------------------------------------------------------------------------
@@ -410,24 +408,25 @@ def pdx_prune_scan_multi_prefetch_pallas(
     thr2 = jnp.asarray(thr, jnp.float32).reshape(1, 1)
     scale2 = scale.reshape(D, 1)
     offset2 = offset.reshape(D, 1)
+    # (P, V) rows travel as (P, 1, V), partition axis squeezed (TPU tiling)
+    slot_row = pl.BlockSpec(
+        (pl.squeezed, 1, V), lambda g, op, ot: (g // nd, 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(P * nd,),
         in_specs=[
             pl.BlockSpec((d_tile, 1), lambda g, op, ot: (ot[g], 0)),
             pl.BlockSpec(
-                (1, V), lambda g, op, ot: (jnp.maximum(op[g], 0), 0)
+                (pl.squeezed, 1, V),
+                lambda g, op, ot: (jnp.maximum(op[g], 0), 0, 0),
             ),
             pl.BlockSpec((1, 1), lambda g, op, ot: (0, 0)),
             pl.BlockSpec((d_tile, 1), lambda g, op, ot: (ot[g], 0)),
             pl.BlockSpec((d_tile, 1), lambda g, op, ot: (ot[g], 0)),
             pl.BlockSpec(memory_space=pl.ANY),  # tiles: manual DMA only
         ],
-        out_specs=[
-            pl.BlockSpec((1, V), lambda g, op, ot: (g // nd, 0)),
-            pl.BlockSpec((1, V), lambda g, op, ot: (g // nd, 0)),
-            pl.BlockSpec((1, V), lambda g, op, ot: (g // nd, 0)),
-        ],
+        out_specs=[slot_row, slot_row, slot_row],
         scratch_shapes=[
             pltpu.VMEM((row_block, V), T.dtype),
             pltpu.SemaphoreType.DMA(()),
@@ -441,14 +440,10 @@ def pdx_prune_scan_multi_prefetch_pallas(
     dists, alive, streamed = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((P, V), jnp.float32),
-            jax.ShapeDtypeStruct((P, V), jnp.float32),
-            jax.ShapeDtypeStruct((P, V), jnp.float32),
-        ],
-        interpret=_interpret(),
+        out_shape=[jax.ShapeDtypeStruct((P, 1, V), jnp.float32)] * 3,
+        interpret=_backend.interpret_mode(),
     )(
         order_p.astype(jnp.int32), order_t.astype(jnp.int32),
-        q2, ids, thr2, scale2, offset2, T,
+        q2, ids.reshape(P, 1, V), thr2, scale2, offset2, T,
     )
-    return dists, alive, streamed
+    return dists.reshape(P, V), alive.reshape(P, V), streamed.reshape(P, V)

@@ -18,7 +18,26 @@ __all__ = [
     "pdx_prune_scan_multi_ref",
     "pdx_prune_scan_multi_dskip_ref",
     "dequantize_ref",
+    "unpack_int4_ref",
 ]
+
+
+def unpack_int4_ref(
+    T: jax.Array, axis: int = 0, dim: int | None = None
+) -> jax.Array:
+    """Packed int4 bytes -> int8 quantization levels along ``axis`` (low
+    nibble = even dim, +8 bias — the ``core.layout`` packing), cut to
+    ``dim`` when given.  Unpacks in 8 bits: an int32 intermediate would
+    be four times the unpacked mirror."""
+    u = T.astype(jnp.uint8)
+    lo = (u & 0xF).astype(jnp.int8) - 8
+    hi = (u >> 4).astype(jnp.int8) - 8
+    shape = list(T.shape)
+    shape[axis] *= 2
+    full = jnp.stack([lo, hi], axis=axis + 1).reshape(shape)
+    if dim is not None and dim != shape[axis]:
+        full = jax.lax.slice_in_dim(full, 0, dim, axis=axis)
+    return full
 
 
 def dequantize_ref(
@@ -37,13 +56,7 @@ def dequantize_ref(
     dim, +8 bias — the ``core.layout`` packing), slicing the doubled axis
     back to logical ``dim`` when given."""
     if packed:
-        p = T.astype(jnp.int32)
-        full = jnp.stack([(p & 0xF) - 8, (p >> 4) - 8], axis=dim_axis + 1)
-        shape = list(T.shape)
-        shape[dim_axis] *= 2
-        T = full.reshape(shape)
-        if dim is not None and dim != shape[dim_axis]:
-            T = jax.lax.slice_in_dim(T, 0, dim, axis=dim_axis)
+        T = unpack_int4_ref(T, dim_axis, dim)
     T32 = T.astype(jnp.float32)
     if scale is None:
         return T32
@@ -80,7 +93,7 @@ def batched_distance_ref(T: jax.Array, Q: jax.Array, metric: str = "l2") -> jax.
     """(D, V), (B, D) -> (B, V); l2 or ip (matmul family)."""
     T32 = T.astype(jnp.float32)
     Q32 = Q.astype(jnp.float32)
-    cross = Q32 @ T32
+    cross = jnp.matmul(Q32, T32, precision=jax.lax.Precision.HIGHEST)
     if metric == "ip":
         return -cross
     qn = jnp.sum(Q32 * Q32, axis=1, keepdims=True)
@@ -135,6 +148,22 @@ def pdx_prune_scan_ref(
     return acc, alive
 
 
+def _dequantize_dims(T, lo, hi, scale, offset, packed):
+    """Dims ``[lo, hi)`` of a (P, D, V) mirror stack as f32.  The multi-
+    partition oracles dequantize one d-tile at a time, so no f32 copy of the
+    whole mirror (the size of the f32 store) ever exists."""
+    if packed:
+        b0 = lo // 2
+        T = unpack_int4_ref(T[:, b0 : (hi + 1) // 2], axis=1)
+        T = T[:, lo - 2 * b0 : hi - 2 * b0]
+    else:
+        T = T[:, lo:hi]
+    T32 = T.astype(jnp.float32)
+    if scale is None:
+        return T32
+    return T32 * scale[None, lo:hi, None] + offset[None, lo:hi, None]
+
+
 def pdx_prune_scan_multi_ref(
     T: jax.Array,
     ids: jax.Array,
@@ -156,15 +185,16 @@ def pdx_prune_scan_multi_ref(
     accumulation, the hypothesis test runs once per d-tile.  ``packed``
     takes an int4 mirror, (P, ceil(dim/2), V) uint8 with logical ``dim``.
     """
-    T32 = dequantize_ref(T, scale, offset, dim_axis=1, packed=packed, dim=dim)
-    P, D, V = T32.shape
+    P, _, V = T.shape
+    D = dim if packed else T.shape[1]
     q32 = q.astype(jnp.float32)
     acc = jnp.zeros((P, V), jnp.float32)
     alive = (ids >= 0).astype(jnp.float32)
     d_seen = 0
     while d_seen < D:
         hi = min(d_seen + d_tile, D)
-        blk = T32[:, d_seen:hi, :] - q32[None, d_seen:hi, None]
+        x = _dequantize_dims(T, d_seen, hi, scale, offset, packed)
+        blk = x - q32[None, d_seen:hi, None]
         contrib = jnp.sum(blk * blk, axis=1)
         acc = acc + contrib * alive
         d_seen = hi
@@ -193,8 +223,8 @@ def pdx_prune_scan_multi_dskip_ref(
     ``streamed`` (P,) count of d-tiles the skipping kernel would actually
     fetch — a tile is streamed iff any of the partition's lanes is alive
     when the tile is reached (the hardware path's conditional DMA)."""
-    T32 = dequantize_ref(T, scale, offset, dim_axis=1, packed=packed, dim=dim)
-    P, D, V = T32.shape
+    P, _, V = T.shape
+    D = dim if packed else T.shape[1]
     q32 = q.astype(jnp.float32)
     acc = jnp.zeros((P, V), jnp.float32)
     alive = (ids >= 0).astype(jnp.float32)
@@ -203,7 +233,8 @@ def pdx_prune_scan_multi_dskip_ref(
     while d_seen < D:
         hi = min(d_seen + d_tile, D)
         streamed = streamed + jnp.any(alive > 0, axis=1).astype(jnp.float32)
-        blk = T32[:, d_seen:hi, :] - q32[None, d_seen:hi, None]
+        x = _dequantize_dims(T, d_seen, hi, scale, offset, packed)
+        blk = x - q32[None, d_seen:hi, None]
         contrib = jnp.sum(blk * blk, axis=1)
         acc = acc + contrib * alive
         d_seen = hi

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..core.topk import topk_init, topk_merge
 from .analysis import collective_bytes_hlo, jaxpr_cost
@@ -132,7 +132,7 @@ def build_pdx_cell(variant: str, mesh, dtype=jnp.float32):
             local, mesh=mesh,
             in_specs=(P(shard_axes), P(shard_axes), P()),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         return fn, (data, ids, Q), (
             NamedSharding(mesh, P(shard_axes)),
@@ -170,7 +170,7 @@ def build_pdx_cell(variant: str, mesh, dtype=jnp.float32):
             local_dim, mesh=mesh,
             in_specs=(P(daxes, "model", None), P(daxes), P(None, "model")),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         return fn, (data, ids, Q), (
             NamedSharding(mesh, P(daxes, "model", None)),

@@ -103,12 +103,9 @@ def _ensure_compile_listener() -> None:
         if _COMPILE_LISTENER:
             return
         _COMPILE_LISTENER = True
-    try:
-        import jax
+    import jax
 
-        jax.monitoring.register_event_listener(_on_jax_event)
-    except Exception:
-        pass  # older jax: counter stays 0, the gate degrades to a no-op
+    jax.monitoring.register_event_listener(_on_jax_event)
 
 
 def jit_compile_count() -> int:
@@ -290,8 +287,7 @@ class VectorServer:
         """Pre-compile every shape bucket (and the shed-nprobe variants, if
         shedding is configured), then snapshot the compile counter for
         ``jit_compiles_since_warmup``.  ``specs`` adds extra SearchSpecs to
-        warm beyond the server default — e.g. a cascade spec (whose pow2
-        survivor/re-rank shape menus compile exhaustively) or a tiered
+        warm beyond the server default — e.g. a cascade spec or a tiered
         spec clients are known to send.  Returns {bucket: executor}."""
         if buckets is None:
             buckets = []
@@ -376,46 +372,53 @@ class VectorServer:
                     self._work.put(_SHUTDOWN)
                     return
                 continue
+            self._dispatch(batch)
 
-            spec = batch[0].spec
-            shed = False
-            if (
-                self.shed_depth is not None
-                and self.engine.ivf is not None
-                and len(self._queue) >= self.shed_depth
-                and spec.nprobe > self.shed_nprobe
-            ):
-                spec = spec.replace(nprobe=self.shed_nprobe)
-                shed = True
-                if _metrics.enabled():
-                    _metrics.counter(
-                        "repro_serve_shed_total", action="nprobe"
-                    )
-
-            Q = np.stack([item.query for item in batch])
-            bucket = pow2_bucket(len(batch), cap=self.max_batch)
-            Qpad = pad_batch(Q, bucket)
-
-            # host half under the store lock: plan + prepare see a consistent
-            # store; the device half runs on the executor thread, which is
-            # also the only mutator — prepare(N+1) overlaps run(N).
-            t_plan0 = time.perf_counter()
-            with self._store_lock:
-                version = getattr(self.engine.store, "version", None)
-                prepared = self._prepare(Qpad, bucket, spec)
-            t_plan1 = time.perf_counter()
-            self._work.put(_Batch(
-                batch, prepared, bucket, Qpad, spec, version,
-                t_plan0, t_plan1, shed,
-            ))
+    def _dispatch(self, batch) -> None:
+        """Plan and prepare one drained batch and hand it to the executor.
+        A method of its own so that nothing of the batch outlives the hand-
+        off: a prepared search holds the store's device arrays, and a local
+        of the batcher loop would keep a replaced store alive while the loop
+        blocks on the next drain."""
+        spec = batch[0].spec
+        shed = False
+        if (
+            self.shed_depth is not None
+            and self.engine.ivf is not None
+            and len(self._queue) >= self.shed_depth
+            and spec.nprobe > self.shed_nprobe
+        ):
+            spec = spec.replace(nprobe=self.shed_nprobe)
+            shed = True
             if _metrics.enabled():
-                _metrics.gauge(
-                    "repro_serve_queue_depth", float(len(self._queue))
+                _metrics.counter(
+                    "repro_serve_shed_total", action="nprobe"
                 )
-                _metrics.observe(
-                    "repro_serve_batch_fill", len(batch) / bucket,
-                    bucket=bucket,
-                )
+
+        Q = np.stack([item.query for item in batch])
+        bucket = pow2_bucket(len(batch), cap=self.max_batch)
+        Qpad = pad_batch(Q, bucket)
+
+        # host half under the store lock: plan + prepare see a consistent
+        # store; the device half runs on the executor thread, which is
+        # also the only mutator — prepare(N+1) overlaps run(N).
+        t_plan0 = time.perf_counter()
+        with self._store_lock:
+            version = getattr(self.engine.store, "version", None)
+            prepared = self._prepare(Qpad, bucket, spec)
+        t_plan1 = time.perf_counter()
+        self._work.put(_Batch(
+            batch, prepared, bucket, Qpad, spec, version,
+            t_plan0, t_plan1, shed,
+        ))
+        if _metrics.enabled():
+            _metrics.gauge(
+                "repro_serve_queue_depth", float(len(self._queue))
+            )
+            _metrics.observe(
+                "repro_serve_batch_fill", len(batch) / bucket,
+                bucket=bucket,
+            )
 
     def _prepare(self, Qpad, bucket, spec):
         import jax.numpy as jnp

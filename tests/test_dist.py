@@ -22,6 +22,9 @@ def run_devices(body: str, n: int = 8) -> str:
     ) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    # the child fakes CPU devices; it must never reach for a chip the
+    # parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
         timeout=600, env=env,
@@ -95,7 +98,7 @@ def test_pipeline_parallel_matches_sequential():
 def test_compressed_psum_dp_grads():
     run_devices("""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.train.compression import compressed_psum
 
     mesh = jax.make_mesh((8,), ("data",))
@@ -105,7 +108,7 @@ def test_compressed_psum_dp_grads():
         return compressed_psum({"g": gl[0]}, "data")["g"]
 
     fn = shard_map(local, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     got = np.asarray(jax.jit(fn)(g))
     want = np.asarray(jnp.mean(g, axis=0))
     err = np.abs(got - want).max()
@@ -129,7 +132,9 @@ def test_gspmd_train_step_8dev_fsdp_tp():
 
     cfg = get_config("llama3.2-3b").reduced()
     model = build_model(cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    # GSPMD: Auto axes (jax.make_mesh defaults to Explicit sharding-in-types)
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     params = model.init(jax.random.key(0))
     oc = OptConfig(warmup_steps=0)
     opt = opt_init(params, oc)

@@ -24,7 +24,8 @@ def test_mesh_shapes_are_lazy_and_correct():
         assert m2.devices.size == 512
         print("OK")
     """)
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=300, env=env)
     assert r.returncode == 0, r.stderr
@@ -86,7 +87,8 @@ def test_jaxpr_cost_counts_attention_flops():
 def test_full_config_cell_compiles_on_512_devices(tmp_path):
     """qwen2-72b prefill_32k: full assigned dims, 16x16 mesh, ShapeDtype
     inputs, lower+compile must succeed (the fastest full cell, ~10s)."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "qwen2-72b",
          "--shape", "prefill_32k", "--mesh", "single_pod",

@@ -256,3 +256,16 @@ def test_prune_scan_prefetch_dtile_skip(use_pallas, rng):
     # a strict saving over partition-granular skip on this data
     assert (s[(s > 0)] < n_tiles).any()
     assert dtile_bytes < part_bytes
+
+
+def test_kernels_interpret_only_on_cpu(monkeypatch):
+    import jax
+
+    from repro.kernels import _backend
+
+    assert _backend.interpret_mode() is True  # this suite runs on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _backend.interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        _backend.interpret_mode()

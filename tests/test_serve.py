@@ -193,10 +193,10 @@ def test_server_single_query_smallest_bucket_no_recompile():
 
 
 def test_server_cascade_warmup_zero_recompiles():
-    """warmup() with a cascade spec compiles the executors' data-dependent
-    pow2 shape menus (survivor compaction S, widened re-rank rk_eff)
-    exhaustively — a served cascade workload whose survivor counts land on
-    shapes the warm batch itself never hit must still mint nothing."""
+    """warmup() with a cascade spec compiles every cascade executable: the
+    survivor counts of a served workload change loop trip counts, never
+    shapes, so queries whose survivors differ from the warm batch's must
+    still mint nothing."""
     eng, X = _vec_engine(n=1024, dim=32)
     spec = eng.spec.replace(
         k=5, cascade=("int8", "f32"), kernel="jnp",
