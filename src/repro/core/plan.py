@@ -968,6 +968,17 @@ def _cascade_finish(master, ids_scan, qt, alive, k, start: TopK) -> TopK:
     return topk_merge(res, start.dists, start.ids)
 
 
+@jax.jit
+def _exact_safe_keep(dists, alive, thr_q):
+    """Keep test of a full-dimension cascade stage: its survivors are the
+    lanes whose whole dequantized distance is within the quantization-
+    inflated START threshold, exact-safe for any tile error.  ADSampling's
+    per-tile estimator is not used on these tiles: it scales a tile's
+    distance by D/d, and quantization error is uneven across dims (int4 at
+    D = 1536 pruned true neighbours on the first tile)."""
+    return alive & (dists <= thr_q)
+
+
 def _finish_partitions(alive, C: int) -> int:
     """Partitions ``_cascade_finish`` re-scores for one query's survivor
     bitmap (a host sync, taken only for the meters)."""
@@ -1038,9 +1049,11 @@ def _exec_cascade_scan(store, pruner, Q, spec, *, ivf, mesh, stats):
                                 precision=jax.lax.Precision.HIGHEST)
                 thr_i, eps_i, d_tile = thr_q, 0.0, rank
             else:
+                # full-dimension stages scan unpruned and keep what the
+                # exact-safe bound on the full distance keeps (see
+                # _exact_safe_keep)
                 qs = qt
-                thr_i = thr_q if prune else jnp.float32(np.inf)
-                eps_i, d_tile = eps0, 64
+                thr_i, eps_i, d_tile = jnp.float32(np.inf), eps0, 64
             sc = mirror.scale if mirror.quantized else None
             off = mirror.offset if mirror.quantized else None
             dists, alive, streamed = _cascade_stage(
@@ -1048,6 +1061,8 @@ def _exec_cascade_scan(store, pruner, Q, spec, *, ivf, mesh, stats):
                 eps_i, d_tile, use_pallas, mirror.packed, mirror.dim,
                 si == 0,
             )
+            if kind != "proj" and prune:
+                alive = _exact_safe_keep(dists, alive, thr_q)
             if meter:
                 n_surv = float(np.asarray(alive.sum()))
                 # realized HBM traffic at d-tile granularity: a partition
@@ -1237,9 +1252,9 @@ def _exec_cascade_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
             Qs = jnp.matmul(Qt, mirror.components,
                             precision=jax.lax.Precision.HIGHEST)
             thr_i, eps_i, d_tile = thr_q, 0.0, rank
-        else:
+        else:  # unpruned scan, exact-safe keep below (_exact_safe_keep)
             Qs = Qt
-            thr_i = thr_q if prune else jnp.full((B,), np.inf, jnp.float32)
+            thr_i = jnp.full((B,), np.inf, jnp.float32)
             eps_i, d_tile = eps0, 64
         # host-synced union -> column chunks of a fixed width
         nz = np.flatnonzero(np.asarray(jnp.any(alive, axis=0)))
@@ -1253,6 +1268,8 @@ def _exec_cascade_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
             mirror.data, jnp.asarray(idx_np), n_chunks, alive, Qs, thr_i,
             sc, off, eps_i, d_tile, use_pallas, mirror.packed, mirror.dim,
         )
+        if kind != "proj" and prune:
+            alive = _exact_safe_keep(dists, alive, thr_q[:, None])
         S = n_chunks * width  # columns gathered, chunk padding included
         if meter:
             surv_b = np.asarray(jnp.sum(alive, axis=1)).astype(np.float64)
