@@ -207,9 +207,11 @@ def run_one_chip(n: int, dim: int, seed: int, kernel: str = "auto") -> None:
             X, index="ivf", pruner="adsampling", scan_dtype="int8",
             kernel=kernel, seed=seed,
         )
-        st = eng.store
-        print(f"[info] nlist={eng.ivf.nlist} partitions={st.num_partitions} "
-              f"capacity={st.capacity}", flush=True)
+        # no local reference to the store: the first insert replaces it,
+        # and HBM holds only one f32 copy of this corpus
+        print(f"[info] nlist={eng.ivf.nlist} "
+              f"partitions={eng.store.num_partitions} "
+              f"capacity={eng.store.capacity}", flush=True)
     print_memory("after IVF build", dev0)
 
     spec = eng.spec.replace(k=K)
