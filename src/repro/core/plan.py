@@ -722,7 +722,8 @@ def _exec_fused_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
     width (IVF engines included — all buckets, like batch-matmul), f32
     re-ranked when the mirror is reduced-precision."""
     mirror = device_mirror(store, spec.scan_dtype)
-    Qt = _transform_batch(pruner, jnp.asarray(Q, jnp.float32))
+    with _trace.span("transform"):
+        Qt = _transform_batch(pruner, jnp.asarray(Q, jnp.float32))
     rk = _rerank_k(spec, store)
     cand = _fused_batch_scan(
         mirror.data, store.ids, Qt, mirror.scale, mirror.offset,

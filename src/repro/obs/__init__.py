@@ -1,10 +1,10 @@
 """repro.obs — runtime telemetry for the whole search stack.
 
-Three cooperating pieces, all behind one process-wide enable flag
+Four cooperating pieces, all behind one process-wide enable flag
 (``repro.obs.metrics.set_enabled`` / the ``REPRO_OBS=1`` environment
 variable).  Disabled is the default and costs one boolean check per
-instrumentation site: no registry mutation, no span objects, no extra
-device synchronization.
+instrumentation site: no registry mutation, no span objects, no profiler
+annotation, no sampler thread, no extra device synchronization.
 
 ``obs.metrics``
     A process-wide, thread-safe ``MetricsRegistry`` of labeled counters,
@@ -15,6 +15,11 @@ device synchronization.
     A span tracer producing per-query ``QueryTrace`` records, kept in a
     bounded ring buffer and exportable as Chrome/Perfetto trace JSON
     (``chrome://tracing`` / https://ui.perfetto.dev).
+
+``obs.host``
+    The host-runtime witnesses: a sampler thread, running while telemetry
+    is on, that counts the process's stalls (late wake-ups), and the
+    garbage collector's time from ``gc.callbacks``.
 
 ``obs.meters``
     Bytes-moved and collective accounting: the demand-bytes model of the
@@ -78,9 +83,11 @@ families:
                                                 compiles observed (the
                                                 zero-recompile-after-warmup
                                                 gate)
-    repro_serve_batch_fill{bucket}              histogram, real / padded lanes
     repro_serve_queue_wait_seconds              histogram, submit -> execution
-    repro_serve_latency_seconds                 histogram, submit -> result
+    repro_host_stalls_total                     sampler wake-ups more than
+                                                100 ms late (obs.host)
+    repro_host_stall_seconds_total              their lateness, summed
+    repro_host_gc_seconds_total{generation}     time inside gc collections
 
 (``repro_store_mutations_total`` also records ``op=adopt`` — a background
 repack swapped in by ``MutablePDXStore.adopt``.)
@@ -101,18 +108,52 @@ the whole call); phases nest under it:
             quantized paths it runs fused on-shard inside the scan and is
             recorded as a zero-width annotation span (``fused="on-shard"``)
     merge   write-head merge + final top-k assembly
+    transform
+            the batch's query rotation (the pruner's transform) in the
+            ``fused-batch`` executor, inside ``scan``; host time to
+            dispatch it, not fenced
 
-Served queries (``repro.serve.vector``) cross threads: the trace is opened
-with ``trace.start_query`` where the batch forms, bound on the executor
-thread with ``trace.use``, and prefixed with a ``queue`` span
-(``trace.span_at``) covering the admission wait — the per-thread current
-trace plus the shared finished-trace ring make concurrent worker traces
-land in one place.
+Served queries (``repro.serve.vector``) cross threads: the trace is started
+with ``trace.start_query`` when the executor takes the batch (its ``t0``),
+bound on the executor thread with ``trace.use``, and finished after the
+batch's futures are resolved.  Spans recorded with ``trace.span_at`` cover
+the waits before it:
+
+    queue     the oldest item's enqueue -> the executor's start
+    admit     the oldest item's enqueue -> the batcher's drain returning
+    plan      ``plan_search`` + ``prepare_execute`` on the batcher
+    handoff   the end of ``plan`` -> the executor's start (the blocked put
+              into the depth-1 hand-off queue and the time spent in it)
+
+``admit``, ``plan`` and ``handoff`` tile ``queue`` up to the batcher's
+work between the drain and planning (expiry check, stacking, padding).
+After ``scan`` and ``merge`` comes
+
+    deliver   the result copies and ``set_result`` calls; the callers'
+              done-callbacks run inside it
+
+The per-thread current trace plus the shared finished-trace ring make
+concurrent worker traces land in one place.
+
+Profiler annotations
+--------------------
+While telemetry is on, every span also opens a ``jax.profiler``
+annotation named ``repro.<span>`` (``repro.plan``, ``repro.scan``,
+``repro.deliver``, ...) on the thread doing the work, and work that runs
+before a trace is bound is annotated with ``trace.activity``:
+``repro.drain`` (the batcher waiting for the first item plus the flush
+window), ``repro.plan`` and ``repro.handoff`` on the batcher,
+``repro.await`` (the executor waiting for work).  ``repro.host_stall``
+marks a stall the host sampler saw, at its end, with ``lost_ms``.  A
+profiler trace thus shows the program's threads on the device's clock.
 
 ``SearchResult.trace`` carries the ``QueryTrace``;
 ``VectorSearchEngine.metrics()`` / ``dump_trace(path)`` surface the registry
 snapshot and the Perfetto export.
 """
-from . import metrics, trace
+from . import host, metrics, trace
 
-__all__ = ["metrics", "trace", "meters"]
+if metrics.enabled():  # REPRO_OBS=1: on from the start, sampler included
+    host.start()
+
+__all__ = ["metrics", "trace", "host", "meters"]

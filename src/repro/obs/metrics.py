@@ -54,8 +54,16 @@ def enabled() -> bool:
 
 
 def set_enabled(on: bool) -> None:
+    """Turn observability on or off; also starts or stops the host-stall
+    sampler (``obs.host``)."""
     global _ENABLED
+    from . import host
+
     _ENABLED = bool(on)
+    if _ENABLED:
+        host.start()
+    else:
+        host.stop()
 
 
 # ------------------------------------------------------------------ registry

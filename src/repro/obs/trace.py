@@ -9,9 +9,9 @@ the device fence — ``fence(x)`` is the explicit ``block_until_ready``
 helper for call sites that hold device values open across a span edge.
 
 Disabled mode (``obs.metrics.enabled() == False``) is a strict no-op: the
-module-level ``query``/``span`` helpers return shared null context
-managers, allocate nothing, touch no thread-local state, and never force a
-device sync.
+module-level ``query``/``span``/``activity`` helpers return shared null
+context managers, allocate nothing, open no profiler annotation, touch no
+thread-local state, and never force a device sync.
 
 Threading model
 ---------------
@@ -31,6 +31,21 @@ a worker), the context-manager API splits into explicit halves:
 ``finish_query`` unbinds the trace only from threads where it is current
 (via ``use``), so finishing on thread B never leaves thread A's
 thread-local pointing at a dead trace.
+
+The profiler's clock
+--------------------
+While observability is on, every span also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<span name>`` on the thread
+doing the work, so a profiler trace shows the program's phases beside the
+device's operations.  ``activity(name)`` does the same for work bound to
+no trace (a serving batcher's drain, planning and hand-off, an executor
+waiting for work, the host sampler's stall marker).  ``_Activity`` is the
+one place that talks to the profiler; ``jax.profiler`` is imported on
+first enabled use.  It keeps its ``perf_counter`` interval in ``t0``/``t1``;
+``_SpanCtx`` lands that interval on the trace.  The serving batcher stamps
+its own times rather than reading an activity's, so that a batch drained
+before telemetry came on still carries its waits.  The ring keeps the bare
+span names and ``perf_counter`` times.
 """
 from __future__ import annotations
 
@@ -51,6 +66,7 @@ __all__ = [
     "query",
     "span",
     "span_at",
+    "activity",
     "start_query",
     "finish_query",
     "use",
@@ -112,29 +128,62 @@ class _NullCtx:
 
 _NULL_CTX = _NullCtx()
 
+#: prefix of the profiler annotations the spans and activities open
+ANNOTATION_PREFIX = "repro."
+_profiler = None  # jax.profiler, imported on first enabled use
 
-class _SpanCtx:
-    __slots__ = ("_tracer", "_trace", "name", "attrs", "_t0", "_depth")
+
+class _Activity:
+    """A ``repro.<name>`` profiler annotation around a block, with its
+    ``perf_counter`` interval kept as ``t0``/``t1``."""
+
+    __slots__ = ("name", "attrs", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, attrs: dict):
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self):
+        global _profiler
+        if _profiler is None:
+            import jax.profiler
+
+            _profiler = jax.profiler
+        self._ann = _profiler.TraceAnnotation(
+            ANNOTATION_PREFIX + self.name, **self.attrs
+        )
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        return False
+
+
+class _SpanCtx(_Activity):
+    """An activity that lands on a trace as a ``Span`` when it closes."""
+
+    __slots__ = ("_tracer", "_trace", "_depth")
 
     def __init__(self, tracer: "Tracer", trace: QueryTrace, name: str,
                  attrs: dict):
+        super().__init__(name, attrs)
         self._tracer = tracer
         self._trace = trace
-        self.name = name
-        self.attrs = attrs
 
     def __enter__(self):
         tl = self._tracer._tl
         self._depth = getattr(tl, "depth", 0)
         tl.depth = self._depth + 1
-        self._t0 = time.perf_counter()
-        return self
+        return super().__enter__()
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
+        super().__exit__(*exc)
         self._tracer._tl.depth = self._depth
         self._trace.spans.append(Span(
-            name=self.name, t0=self._t0, t1=t1, depth=self._depth,
+            name=self.name, t0=self.t0, t1=self.t1, depth=self._depth,
             attrs=self.attrs,
         ))
         return False
@@ -225,6 +274,15 @@ class Tracer:
             name=name, t0=float(t0), t1=float(t1),
             depth=getattr(self._tl, "depth", 0), attrs=attrs,
         ))
+
+    def activity(self, name: str, **attrs):
+        """Context manager opening the ``repro.<name>`` profiler annotation
+        on the calling thread, bound to no trace; yields the ``_Activity``
+        (its interval in ``t0``/``t1`` once closed).  A shared no-op
+        yielding ``None`` when disabled."""
+        if not _metrics.enabled():
+            return _NULL_CTX
+        return _Activity(name, attrs)
 
     # -------------------------------------------- cross-thread serving API
     def start_query(self, **attrs) -> Optional[QueryTrace]:
@@ -331,6 +389,10 @@ def span(name: str, **attrs):
 
 def span_at(name: str, t0: float, t1: float, **attrs) -> None:
     _TRACER.span_at(name, t0, t1, **attrs)
+
+
+def activity(name: str, **attrs):
+    return _TRACER.activity(name, **attrs)
 
 
 def start_query(**attrs) -> Optional[QueryTrace]:

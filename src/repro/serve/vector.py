@@ -21,11 +21,8 @@ batcher
 executor
     The sole store mutator.  Pops prepared batches (executes them with no
     lock held — nothing else may mutate), mutations (``insert``/``delete``
-    applied under the store lock), and maintenance swaps.  Records the
-    cross-thread query trace: ``start_query``/``use``/``finish_query``
-    plus ``span_at`` for the queue wait and the batcher-side plan time, so
-    every serving query lands in the shared trace ring with a ``queue``
-    span in front of the usual plan → scan → merge taxonomy.
+    applied under the store lock), and maintenance swaps, and resolves each
+    batch's futures, so the callers' done-callbacks run on this thread.
 
 maintenance (optional)
     Periodically clones the store under the lock, runs
@@ -38,6 +35,20 @@ maintenance (optional)
     falls back to discard-and-retry.  Compaction never blocks a query;
     BSA recalibration (which rewrites live vectors) deliberately stays
     with the synchronous ``engine.compact()``.
+
+Tracing (only while ``repro.obs`` is on): each served batch gets one
+``QueryTrace``, started when the executor takes the batch (its ``t0``) and
+finished after its futures are resolved.  Spans recorded after the fact
+with ``span_at`` cover the batch's waits: ``queue`` (the oldest item's
+enqueue to the executor's start), tiled by ``admit`` (to the drain's
+return), ``plan`` (``plan_search`` + ``prepare_execute`` on the batcher)
+and ``handoff`` (the end of planning to the executor's start: the blocked
+put and the time in the depth-1 queue).  Then come the engine's ``scan``
+(with ``transform`` and ``rerank`` inside it) and ``merge``, and
+``deliver``, the result copies and ``set_result`` calls.  Each thread's
+work is also annotated on the profiler's clock: ``repro.drain``,
+``repro.plan`` and ``repro.handoff`` on the batcher, ``repro.await`` and
+every span of the trace on the executor (``repro.obs.trace``).
 
 Backpressure and deadlines: the admission queue is bounded — a full queue
 rejects at ``submit`` time with ``ServerOverloaded`` (bounded queue =
@@ -124,22 +135,27 @@ _SHUTDOWN = _Shutdown()
 
 
 class _Batch:
+    """A prepared batch on its way to the executor.  ``t_drained`` (the
+    drain's return) and ``t_plan0``/``t_plan1`` (planning) place the
+    batch's ``admit``, ``plan`` and ``handoff`` spans."""
+
     __slots__ = (
         "items", "prepared", "bucket", "Qpad", "spec",
-        "store_version", "t_plan0", "t_plan1", "shed",
+        "store_version", "shed", "t_drained", "t_plan0", "t_plan1",
     )
 
     def __init__(self, items, prepared, bucket, Qpad, spec, store_version,
-                 t_plan0, t_plan1, shed):
+                 shed, t_drained, t_plan0, t_plan1):
         self.items = items
         self.prepared = prepared
         self.bucket = bucket
         self.Qpad = Qpad
         self.spec = spec
         self.store_version = store_version
+        self.shed = shed
+        self.t_drained = t_drained
         self.t_plan0 = t_plan0
         self.t_plan1 = t_plan1
-        self.shed = shed
 
 
 class _Mutation:
@@ -361,20 +377,22 @@ class VectorServer:
 
     def _batcher_loop(self) -> None:
         while True:
-            batch, expired = self._queue.drain(
-                self.max_batch,
-                window_s=self.flush_interval_s,
-                timeout_s=0.05,
-            )
+            with _trace.activity("drain"):
+                batch, expired = self._queue.drain(
+                    self.max_batch,
+                    window_s=self.flush_interval_s,
+                    timeout_s=0.05,
+                )
+            t_drained = time.perf_counter()
             self._fail_expired(expired)
             if not batch:
                 if self._queue.closed and not len(self._queue):
                     self._work.put(_SHUTDOWN)
                     return
                 continue
-            self._dispatch(batch)
+            self._dispatch(batch, t_drained)
 
-    def _dispatch(self, batch) -> None:
+    def _dispatch(self, batch, t_drained) -> None:
         """Plan and prepare one drained batch and hand it to the executor.
         A method of its own so that nothing of the batch outlives the hand-
         off: a prepared search holds the store's device arrays, and a local
@@ -403,21 +421,19 @@ class VectorServer:
         # store; the device half runs on the executor thread, which is
         # also the only mutator — prepare(N+1) overlaps run(N).
         t_plan0 = time.perf_counter()
-        with self._store_lock:
-            version = getattr(self.engine.store, "version", None)
-            prepared = self._prepare(Qpad, bucket, spec)
+        with _trace.activity("plan"):
+            with self._store_lock:
+                version = getattr(self.engine.store, "version", None)
+                prepared = self._prepare(Qpad, bucket, spec)
         t_plan1 = time.perf_counter()
-        self._work.put(_Batch(
-            batch, prepared, bucket, Qpad, spec, version,
-            t_plan0, t_plan1, shed,
-        ))
+        work = _Batch(batch, prepared, bucket, Qpad, spec, version, shed,
+                      t_drained, t_plan0, t_plan1)
+        # blocks while the executor still holds the batch before this one
+        with _trace.activity("handoff"):
+            self._work.put(work)
         if _metrics.enabled():
             _metrics.gauge(
                 "repro_serve_queue_depth", float(len(self._queue))
-            )
-            _metrics.observe(
-                "repro_serve_batch_fill", len(batch) / bucket,
-                bucket=bucket,
             )
 
     def _prepare(self, Qpad, bucket, spec):
@@ -435,7 +451,8 @@ class VectorServer:
 
     def _executor_loop(self) -> None:
         while True:
-            work = self._work.get()
+            with _trace.activity("await"):
+                work = self._work.get()
             if isinstance(work, _Shutdown):
                 return
             if isinstance(work, _Mutation):
@@ -520,40 +537,52 @@ class VectorServer:
         )
         try:
             with _trace.use(tr):
-                t_enq = min(item.t_enqueue for item in b.items)
-                _trace.span_at("queue", t_enq, t_run, depth_at_drain=len(b.items))
-                _trace.span_at("plan", b.t_plan0, b.t_plan1)
+                if tr is not None:
+                    self._record_waits(b, t_run)
                 ids, dists = b.prepared.run()
-        except BaseException as e:
-            _trace.finish_query(tr)
+                t_done = time.perf_counter()
+                if _metrics.enabled():
+                    self._count(b, t_run)
+                with _trace.span("deliver", n_queries=len(b.items)):
+                    self._deliver(b, ids, dists, t_done)
+        except BaseException as e:  # surface on the callers' futures
             for item in b.items:
                 if not item.future.done():
                     item.future.set_exception(e)
-            return
-        _trace.finish_query(tr)
+        finally:
+            _trace.finish_query(tr)
 
-        t_done = time.perf_counter()
-        en = _metrics.enabled()
-        if en:
-            _metrics.counter(
-                "repro_serve_batches_total", bucket=b.bucket,
-                executor=b.prepared.plan.executor, shed=b.shed,
+    @staticmethod
+    def _record_waits(b: _Batch, t_run: float) -> None:
+        """The batch's waits before the executor took it, as spans: ``queue``
+        (oldest enqueue -> executor start), tiled by ``admit`` (-> the
+        drain's return), ``plan`` and ``handoff`` (-> executor start)."""
+        t_enq = min(item.t_enqueue for item in b.items)
+        _trace.span_at("queue", t_enq, t_run, depth_at_drain=len(b.items))
+        _trace.span_at("admit", t_enq, b.t_drained)
+        _trace.span_at("plan", b.t_plan0, b.t_plan1)
+        _trace.span_at("handoff", b.t_plan1, t_run)
+
+    @staticmethod
+    def _count(b: _Batch, t_run: float) -> None:
+        _metrics.counter(
+            "repro_serve_batches_total", bucket=b.bucket,
+            executor=b.prepared.plan.executor, shed=b.shed,
+        )
+        _metrics.counter("repro_serve_queries_total", float(len(b.items)))
+        for item in b.items:
+            _metrics.observe(
+                "repro_serve_queue_wait_seconds", t_run - item.t_enqueue
             )
-            _metrics.counter(
-                "repro_serve_queries_total", float(len(b.items))
-            )
+
+    @staticmethod
+    def _deliver(b: _Batch, ids, dists, t_done: float) -> None:
+        """Resolve the batch's futures; their done-callbacks run here."""
         for i, item in enumerate(b.items):
-            if en:
-                _metrics.observe(
-                    "repro_serve_queue_wait_seconds", t_run - item.t_enqueue
-                )
-                _metrics.observe(
-                    "repro_serve_latency_seconds", t_done - item.t_enqueue
-                )
             if item.future.done():
                 continue
             if item.deadline is not None and t_done > item.deadline:
-                if en:
+                if _metrics.enabled():
                     _metrics.counter(
                         "repro_serve_deadline_expired_total", where="result"
                     )

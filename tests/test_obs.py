@@ -520,3 +520,127 @@ def test_use_restores_previous_binding(obs):
     assert {t.trace_id for t in tr.traces()} == {
         served.trace_id, outer.trace_id
     }
+
+
+# ---------------------------------------------------------------------------
+# Host runtime: the stall sampler and the GC clock (obs.host)
+# ---------------------------------------------------------------------------
+def _sampler_threads():
+    import threading
+
+    return [t for t in threading.enumerate()
+            if t.name == "repro-host-sampler"]
+
+
+def test_host_sampler_counts_a_forced_stall_and_stops(obs):
+    """A pure-Python busy loop under a long switch interval keeps the
+    sampler off the interpreter for 0.3 s: one stall, about that long.
+    Turning telemetry off stops the thread."""
+    import sys
+    import time
+
+    assert len(_sampler_threads()) == 1
+    assert obs.get("repro_host_stalls_total") == 0.0
+    time.sleep(0.05)                      # the sampler is asleep in a step
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(5.0)
+    try:
+        t_end = time.perf_counter() + 0.3
+        while time.perf_counter() < t_end:
+            pass
+    finally:
+        sys.setswitchinterval(old)
+    deadline = time.perf_counter() + 5.0
+    while (obs.get("repro_host_stalls_total") < 1
+           and time.perf_counter() < deadline):
+        time.sleep(0.01)
+    assert obs.get("repro_host_stalls_total") >= 1.0
+    assert obs.get("repro_host_stall_seconds_total") > 0.25
+    metrics.set_enabled(False)
+    assert _sampler_threads() == []
+
+
+def test_host_counters_start_at_zero_and_gc_time_is_counted(obs):
+    """The sampler registers its counters at zero (a reader tells 'never
+    stalled' from 'not sampled'), and a collection adds to its generation's
+    seconds once the sampler has flushed it."""
+    import gc
+    import time
+
+    snap = obs.snapshot()["counters"]
+    assert snap["repro_host_stalls_total"] == {"": 0.0}
+    assert snap["repro_host_stall_seconds_total"] == {"": 0.0}
+    assert set(snap["repro_host_gc_seconds_total"]) == {
+        "generation=0", "generation=1", "generation=2"}
+    gc.collect()
+    deadline = time.perf_counter() + 5.0
+    while (obs.get("repro_host_gc_seconds_total", generation=2) == 0.0
+           and time.perf_counter() < deadline):
+        time.sleep(0.01)
+    assert obs.get("repro_host_gc_seconds_total", generation=2) > 0.0
+
+
+def test_activity_stamps_its_interval_and_is_null_when_off(obs):
+    import time
+
+    with trace.activity("drain") as act:
+        time.sleep(0.002)
+    assert act.name == "drain" and act.t1 - act.t0 >= 0.002
+    assert trace.get_tracer().last() is None  # bound to no trace
+    metrics.set_enabled(False)
+    with trace.activity("drain") as act:
+        assert act is None
+
+
+def test_concurrent_enable_toggles_leave_one_sampler_or_none(obs):
+    """Threads flipping telemetry on and off under a short switch interval
+    never leave two samplers running, and the last 'off' leaves none."""
+    import sys
+    import threading
+
+    seen = []
+
+    def flip():
+        for _ in range(30):
+            metrics.set_enabled(True)
+            seen.append(len(_sampler_threads()))
+            metrics.set_enabled(False)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=flip) for _ in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert max(seen) <= 1
+    metrics.set_enabled(False)
+    assert _sampler_threads() == []
+
+
+def test_env_flag_starts_the_sampler_like_set_enabled():
+    """``REPRO_OBS=1`` turns telemetry on at import, sampler included, so
+    both ways of turning it on count stalls; off stops it."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import threading, repro.obs\n"
+        "from repro.obs import metrics\n"
+        "names = lambda: [t.name for t in threading.enumerate()]\n"
+        "assert metrics.enabled() and 'repro-host-sampler' in names()\n"
+        "snap = metrics.get_registry().snapshot()['counters']\n"
+        "assert snap['repro_host_stall_seconds_total'] == {'': 0.0}\n"
+        "metrics.set_enabled(False)\n"
+        "assert 'repro-host-sampler' not in names()\n"
+    )
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env = dict(os.environ, REPRO_OBS="1", PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr

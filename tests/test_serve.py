@@ -323,3 +323,119 @@ def test_store_adopt_version_fence():
     clone2.repack()
     assert ms.adopt(clone2, expect_version=base2)
     assert ms.num_vectors == 103 and ms.head_count == 0
+
+
+# ------------------------------------------------ tracing the served pipeline
+@pytest.fixture
+def obs_on():
+    from repro.obs import metrics, trace
+
+    reg, tr = metrics.get_registry(), trace.get_tracer()
+    reg.reset()
+    tr.clear()
+    metrics.set_enabled(True)
+    try:
+        yield reg, tr
+    finally:
+        metrics.set_enabled(False)
+        reg.reset()
+        tr.clear()
+
+
+def _serve_rounds(srv, X, rounds=3, per_round=4):
+    """Submit ``rounds`` bursts, each awaited before the next, so every
+    batch is planned while the executor is idle."""
+    for r in range(rounds):
+        futs = [srv.submit(X[r * per_round + i]) for i in range(per_round)]
+        for i, f in enumerate(futs):
+            assert f.result(timeout=60)[0][0] == r * per_round + i
+
+
+def test_served_batch_writes_profiler_annotations(obs_on, tmp_path):
+    """Under the profiler, every thread of the pipeline leaves its
+    ``repro.*`` host events: the batcher's drain, plan and hand-off, the
+    executor's wait for work, scan, query rotation, re-rank and delivery."""
+    import glob
+
+    eng, X = _vec_engine()
+    spec = eng.spec.replace(k=5, executor="fused-batch", scan_dtype="int8",
+                            kernel="jnp")
+    with VectorServer(eng, spec=spec, max_batch=8) as srv:
+        srv.warmup()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _serve_rounds(srv, X, rounds=2)
+        finally:
+            jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    threads: dict = {}
+    for plane in pd.planes:
+        for i, line in enumerate(plane.lines):   # one line per thread
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    threads.setdefault(ev.name, set()).add((plane.name, i))
+    for name in ("repro.drain", "repro.plan", "repro.handoff",
+                 "repro.await", "repro.scan", "repro.transform",
+                 "repro.rerank", "repro.deliver"):
+        assert name in threads, sorted(threads)
+    # each thread's work is on that thread's line
+    assert threads["repro.drain"] == threads["repro.handoff"]
+    assert threads["repro.await"] == threads["repro.deliver"]
+    assert threads["repro.drain"].isdisjoint(threads["repro.deliver"])
+
+
+def test_served_waits_tile_the_queue_span(obs_on):
+    """``admit``, ``plan`` and ``handoff`` are disjoint, in that order, and
+    add up to ``queue`` within 2 ms; the trace still starts at the
+    executor's start, before any of its execution spans."""
+    _, tr = obs_on
+    eng, X = _vec_engine()
+    spec = eng.spec.replace(k=5, executor="batch-matmul")
+    with VectorServer(eng, spec=spec, max_batch=8) as srv:
+        srv.warmup()
+        tr.clear()
+        _serve_rounds(srv, X, rounds=4)
+    served = [t for t in tr.traces() if t.attrs.get("served")]
+    assert len(served) >= 4
+    for t in served:
+        q, a, p, h = (t.find(n) for n in ("queue", "admit", "plan",
+                                          "handoff"))
+        assert q.t0 == a.t0 and h.t1 == q.t1
+        assert a.t0 <= a.t1 <= p.t0 <= p.t1 == h.t0 <= h.t1
+        total = a.duration_s + p.duration_s + h.duration_s
+        assert abs(total - q.duration_s) < 2e-3, (total, q.duration_s)
+        scan, deliver = t.find("scan"), t.find("deliver")
+        assert q.t1 <= t.t0 <= scan.t0 < scan.t1 <= deliver.t0
+        assert deliver.t1 <= t.t1
+        assert deliver.attrs["n_queries"] == t.attrs["n_queries"]
+
+
+def test_disabled_server_makes_no_annotation_and_no_sampler(monkeypatch):
+    from repro.obs import metrics
+
+    def refuse(*a, **kw):
+        raise AssertionError("a profiler annotation was made while off")
+
+    assert not metrics.enabled()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    eng, X = _vec_engine()
+    spec = eng.spec.replace(k=5, executor="fused-batch", scan_dtype="int8",
+                            kernel="jnp")
+    with VectorServer(eng, spec=spec, max_batch=8) as srv:
+        _serve_rounds(srv, X, rounds=2)
+        names = {t.name for t in threading.enumerate()}
+    assert "repro-host-sampler" not in names
+    assert {"serve-batcher", "serve-executor"} <= names
+
+
+def test_removed_serving_histograms_are_absent(obs_on):
+    reg, _ = obs_on
+    eng, X = _vec_engine()
+    spec = eng.spec.replace(k=5, executor="batch-matmul")
+    with VectorServer(eng, spec=spec, max_batch=8) as srv:
+        _serve_rounds(srv, X, rounds=1)
+    hists = reg.snapshot()["histograms"]
+    assert hists["repro_serve_queue_wait_seconds"][""]["count"] == 4
+    assert "repro_serve_latency_seconds" not in hists
+    assert "repro_serve_batch_fill" not in hists
