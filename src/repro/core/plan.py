@@ -99,6 +99,7 @@ from .spec import SearchSpec, parse_cascade_stage
 from .topk import (
     TopK,
     rerank_positions,
+    scan_topk,
     topk_from_batch,
     topk_init,
     topk_merge,
@@ -679,34 +680,25 @@ def _rerank_k(spec: SearchSpec, store) -> int:
 def _fused_batch_scan(
     mdata, ids, Qt, scale, offset, rk, metric, use_pallas, quantized,
     packed: bool = False, dim: int | None = None,
-) -> TopK:
+) -> tuple[TopK, Optional[jax.Array]]:
     """Scan every mirror tile with the quantized batch kernel -> per-query
-    top-``rk`` flat positions (PAD lanes carry position -1)."""
+    top-``rk`` flat positions (PAD lanes carry position -1), and the flag
+    of ``scan_topk``'s fallback to the per-step merge (None: merge only)."""
     from ..kernels.ops import batched_distance_quant_op
     from ..kernels.ref import dequantize_ref
 
-    P = mdata.shape[0]
-    C = mdata.shape[2]
     sc = scale if quantized else None
     off = offset if quantized else None
-    pos = jnp.arange(P * C, dtype=jnp.int32).reshape(P, C)
-    pos = jnp.where(ids >= 0, pos, -1)
 
-    def body(state: TopK, inp):
-        tile, tpos = inp
+    def distances(tile):
         if metric == "l1":  # no matmul form; dequantize + vmapped VPU scan
             t32 = dequantize_ref(tile, sc, off, packed=packed, dim=dim)
-            dmat = jax.vmap(lambda q: pdx_distance(t32, q, "l1"))(Qt)
-        else:
-            dmat = batched_distance_quant_op(
-                tile, Qt, sc, off, metric, use_pallas,
-                packed=packed, dim=dim,
-            )
-        return jax.vmap(topk_merge, (0, 0, None))(state, dmat, tpos), None
+            return jax.vmap(lambda q: pdx_distance(t32, q, "l1"))(Qt)
+        return batched_distance_quant_op(
+            tile, Qt, sc, off, metric, use_pallas, packed=packed, dim=dim,
+        )
 
-    init = jax.vmap(lambda _: topk_init(rk))(jnp.arange(Qt.shape[0]))
-    state, _ = jax.lax.scan(body, init, (mdata, pos))
-    return state
+    return scan_topk(distances, mdata, ids < 0, Qt.shape[0], rk)
 
 
 @jax.jit
@@ -725,7 +717,7 @@ def _exec_fused_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
     with _trace.span("transform"):
         Qt = _transform_batch(pruner, jnp.asarray(Q, jnp.float32))
     rk = _rerank_k(spec, store)
-    cand = _fused_batch_scan(
+    cand, fallback = _fused_batch_scan(
         mirror.data, store.ids, Qt, mirror.scale, mirror.offset,
         rk, spec.metric, _resolve_pallas(spec), mirror.quantized,
         packed=mirror.packed, dim=mirror.dim,
@@ -752,7 +744,14 @@ def _exec_fused_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
                 "repro_device_bytes_total", float(B) * rk * D * 4,
                 executor="fused-batch", component="rerank", dtype="f32",
             )
-    return np.asarray(res.ids), np.asarray(res.dists)
+    out = np.asarray(res.ids), np.asarray(res.dists)
+    if fallback is not None and _metrics.enabled():
+        # read after the answers, so the flag costs no sync of its own
+        _metrics.counter(
+            "repro_slot_topk_batches_total",
+            outcome="fallback" if bool(fallback) else "certified",
+        )
+    return out
 
 
 @register_executor("fused-scan")

@@ -141,6 +141,34 @@ def test_fused_ivf_store_scans_exactly(rng):
         assert set(r1.ids.tolist()) == set(gt_ids[0].tolist()), dtype
 
 
+def test_fused_batch_counts_its_slot_topk_batch():
+    """With telemetry on, one fused-batch search counts one slot-wise top-k
+    batch (certified on this data) and answers as it does with it off."""
+    from repro.obs import metrics, trace
+
+    X, Q = make_dataset(1900, 50, "normal", n_queries=4, seed=7)
+    gt_ids, _ = ground_truth(X, Q, k=5)
+    eng = VectorSearchEngine.build(X, pruner="adsampling", capacity=256)
+    spec = SearchSpec(k=5, scan_dtype="int8", kernel="jnp",
+                      executor="fused-batch")
+    off = eng.search(Q, spec)
+    reg = metrics.get_registry()
+    reg.reset()
+    metrics.set_enabled(True)
+    try:
+        on = eng.search(Q, spec)
+        name = "repro_slot_topk_batches_total"
+        assert reg.sum(name) == 1.0
+        assert reg.get(name, outcome="certified") == 1.0
+    finally:
+        metrics.set_enabled(False)
+        reg.reset()
+        trace.get_tracer().clear()
+    np.testing.assert_array_equal(on.ids, off.ids)
+    np.testing.assert_array_equal(on.dists, off.dists)
+    assert recall_at_k(on.ids, gt_ids) == 1.0
+
+
 # ------------------------------------------------------------- churned store
 def test_fused_executors_on_churned_mutable_store():
     """A churned MutablePDXStore answers through the quantized fused path

@@ -10,6 +10,8 @@ VMEM than a kernel may use.  Interpret mode can show none of these.
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -140,5 +142,22 @@ def test_batched_cascade_stage_compiles(compiled, D):
         compiled((B, S), jnp.bool_),
         compiled((B, D), jnp.float32),
         compiled((B,), jnp.float32),
+        scale, offset,
+    )
+
+
+@pytest.mark.parametrize("D", [1536, 96])
+def test_fused_batch_scan_compiles(compiled, D):
+    """The whole fused-batch program: the kernel, the slot-wise top-R
+    scan, and the per-step merge it falls back to under ``lax.cond``."""
+    from repro.core.plan import _fused_batch_scan
+
+    scale, offset = _dequant(compiled, D, True)
+    _assert_kernel_compiles(
+        functools.partial(_fused_batch_scan, rk=40, metric="l2",
+                          use_pallas=True, quantized=True),
+        compiled((P_PARTS, D, C), jnp.int8),
+        compiled((P_PARTS, C), jnp.int32),
+        compiled((B, D), jnp.float32),
         scale, offset,
     )
