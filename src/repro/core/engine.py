@@ -184,6 +184,7 @@ class VectorSearchEngine:
         rerank_mult: int = 4,
         cascade: Optional[tuple] = None,
         route_dtype: str = "f32",
+        nprobe: Optional[int] = None,
         tree="auto",
         super_k: Optional[int] = None,
         nprobe_super: Optional[int] = None,
@@ -212,7 +213,7 @@ class VectorSearchEngine:
                 sel_frac=sel_frac, group=group, routing=routing,
                 scan_dtype=scan_dtype, kernel=kernel,
                 rerank_mult=rerank_mult, cascade=cascade,
-                route_dtype=route_dtype,
+                route_dtype=route_dtype, nprobe=nprobe,
             )
         return cls(store=store, pruner=pr, spec=spec, ivf=ivf, mesh=mesh,
                    zone_size=zone_size)

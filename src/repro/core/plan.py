@@ -330,6 +330,9 @@ def _host_plan(spec, n_queries, ivf, plan, note: str = "") -> ExecutionPlan:
     if spec.cascade is not None:
         body = "pallas" if _resolve_pallas(spec) else "jnp"
         where = "IVF-routed START, " if ivf is not None else ""
+        if ivf is not None and spec.nprobe is not None:
+            note += (f"(nprobe={spec.nprobe} ignored: the cascade scans "
+                     "every bucket) ")
         if n_queries > 1:
             return plan(
                 "cascade-batch",
@@ -344,14 +347,24 @@ def _host_plan(spec, n_queries, ivf, plan, note: str = "") -> ExecutionPlan:
         )
     if _wants_fused(spec):
         body = "pallas" if _resolve_pallas(spec) else "jnp"
+        routed = ivf is not None and spec.nprobe is not None
         if n_queries == 1 and spec.metric == "l2":
             where = "IVF-routed START, " if ivf is not None else ""
+            if routed:
+                where += (f"nprobe={spec.nprobe}: partitions of unprobed "
+                          "buckets killed, ")
             return plan(
                 "fused-scan",
                 note + f"fused megakernel mirror scan ({where}scan_dtype="
                        f"{spec.scan_dtype}, kernel={body})",
             )
-        extra = "; IVF store scanned exactly, all buckets" if ivf else ""
+        extra = ""
+        if routed:
+            extra = (f"; IVF-routed on the device: each query masked to its "
+                     f"nprobe={spec.nprobe} nearest buckets, the batch's "
+                     "union of their partitions scanned")
+        elif ivf is not None:
+            extra = "; IVF store scanned exactly, all buckets (nprobe=None)"
         return plan(
             "fused-batch",
             note + f"fused batched mirror scan (scan_dtype={spec.scan_dtype}"
@@ -582,16 +595,21 @@ def _merge_write_head(
     )
 
 
-def _exact_scan_stats(stats: Optional[SearchStats], store, B: int) -> None:
+def _exact_scan_stats(stats: Optional[SearchStats], store, B: int,
+                      parts: Optional[np.ndarray] = None) -> None:
     """Work accounting for the exact full-scan executors: every live value
-    is computed, nothing avoided — the honest baseline ``pruning_power``
+    of the partitions scanned (all, or the (P,) mask ``parts``) is
+    computed, nothing avoided — the honest baseline ``pruning_power``
     compares against."""
     if stats is None:
         return
-    work = float(np.asarray(store.counts).sum()) * store.dim * B
+    counts = np.asarray(store.counts)
+    if parts is None:
+        parts = np.ones(len(counts), bool)
+    work = float(counts[parts].sum()) * store.dim * B
     stats.values_total += work
     stats.values_computed += work
-    stats.partitions_visited += store.num_partitions * B
+    stats.partitions_visited += int(parts.sum()) * B
 
 
 @register_executor("adaptive")
@@ -679,11 +697,14 @@ def _rerank_k(spec: SearchSpec, store) -> int:
 )
 def _fused_batch_scan(
     mdata, ids, Qt, scale, offset, rk, metric, use_pallas, quantized,
-    packed: bool = False, dim: int | None = None,
-) -> tuple[TopK, Optional[jax.Array]]:
-    """Scan every mirror tile with the quantized batch kernel -> per-query
-    top-``rk`` flat positions (PAD lanes carry position -1), and the flag
-    of ``scan_topk``'s fallback to the per-step merge (None: merge only)."""
+    packed: bool = False, dim: int | None = None, probe=None,
+) -> tuple[TopK, Optional[jax.Array], Optional[jax.Array]]:
+    """Scan the mirror tiles with the quantized batch kernel -> per-query
+    top-``rk`` flat positions (PAD lanes carry position -1), the flag of
+    ``scan_topk``'s fallback to the per-step merge (None: merge only), and
+    the (P,) partitions scanned (None: every one).  ``probe`` (B, P)
+    restricts each query to its probed partitions; then only their union
+    is read."""
     from ..kernels.ops import batched_distance_quant_op
     from ..kernels.ref import dequantize_ref
 
@@ -698,7 +719,9 @@ def _fused_batch_scan(
             tile, Qt, sc, off, metric, use_pallas, packed=packed, dim=dim,
         )
 
-    return scan_topk(distances, mdata, ids < 0, Qt.shape[0], rk)
+    cand, fallback = scan_topk(distances, mdata, ids < 0, Qt.shape[0], rk,
+                               probe=probe)
+    return cand, fallback, None if probe is None else jnp.any(probe, axis=0)
 
 
 @jax.jit
@@ -710,17 +733,25 @@ def _positions_to_ids(store_ids, cand: TopK) -> TopK:
 
 @register_executor("fused-batch")
 def _exec_fused_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
-    """Exact-over-store scan of the device mirror at ``spec.scan_dtype``
-    width (IVF engines included — all buckets, like batch-matmul), f32
-    re-ranked when the mirror is reduced-precision."""
+    """Batched scan of the device mirror at ``spec.scan_dtype`` width, f32
+    re-ranked when the mirror is reduced-precision.  Without ``nprobe`` (or
+    an IVF index) every partition is scanned, exactly; with it each query
+    is routed on the device to its ``nprobe`` nearest buckets and masked to
+    their partitions, and only the batch's union of them is read."""
     mirror = device_mirror(store, spec.scan_dtype)
     with _trace.span("transform"):
         Qt = _transform_batch(pruner, jnp.asarray(Q, jnp.float32))
+    probe = None
+    if ivf is not None and spec.nprobe is not None:
+        with _trace.span("route", nprobe=spec.nprobe):
+            probe, _ = ivf.probe_mask(
+                Qt, spec.nprobe, spec.metric, spec.route_dtype
+            )
     rk = _rerank_k(spec, store)
-    cand, fallback = _fused_batch_scan(
+    cand, fallback, scanned = _fused_batch_scan(
         mirror.data, store.ids, Qt, mirror.scale, mirror.offset,
         rk, spec.metric, _resolve_pallas(spec), mirror.quantized,
-        packed=mirror.packed, dim=mirror.dim,
+        packed=mirror.packed, dim=mirror.dim, probe=probe,
     )
     if spec.scan_dtype == "f32":
         res = _positions_to_ids(store.ids, cand)
@@ -729,14 +760,20 @@ def _exec_fused_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
             res = _trace.fence(rerank_positions(
                 store.data, store.ids, Qt, cand, spec.k, spec.metric
             ))
+    out = np.asarray(res.ids), np.asarray(res.dists)
+    # read after the answers, so the flag and the union cost no sync of
+    # their own, and only when something counts them
+    counting = stats is not None or _metrics.enabled()
+    parts = np.asarray(scanned) if scanned is not None and counting else None
     B = Q.shape[0]
-    _exact_scan_stats(stats, store, B)
+    _exact_scan_stats(stats, store, B, parts)
     if _metrics.enabled():
         P, C = mirror.data.shape[0], mirror.data.shape[2]
         D = mirror.dim  # logical D (packed int4 halves the stored axis)
+        n_scan = P if parts is None else int(parts.sum())
         _metrics.counter(
             "repro_device_bytes_total",
-            float(B) * P * D * C * mirror.bytes_per_value,
+            float(B) * n_scan * D * C * mirror.bytes_per_value,
             executor="fused-batch", component="scan", dtype=mirror.dtype,
         )
         if spec.scan_dtype != "f32":
@@ -744,14 +781,22 @@ def _exec_fused_batch(store, pruner, Q, spec, *, ivf, mesh, stats):
                 "repro_device_bytes_total", float(B) * rk * D * 4,
                 executor="fused-batch", component="rerank", dtype="f32",
             )
-    out = np.asarray(res.ids), np.asarray(res.dists)
-    if fallback is not None and _metrics.enabled():
-        # read after the answers, so the flag costs no sync of its own
-        _metrics.counter(
-            "repro_slot_topk_batches_total",
-            outcome="fallback" if bool(fallback) else "certified",
-        )
+        if parts is not None:
+            _count_probe(n_scan, P, "fused-batch")
+        if fallback is not None:
+            _metrics.counter(
+                "repro_slot_topk_batches_total",
+                outcome="fallback" if bool(fallback) else "certified",
+            )
     return out
+
+
+def _count_probe(scanned: int, P: int, executor: str) -> None:
+    """Partitions a routed batch scanned, beside the store's."""
+    _metrics.counter("repro_ivf_probe_partitions_total", float(scanned),
+                     executor=executor, set="scanned")
+    _metrics.counter("repro_ivf_probe_partitions_total", float(P),
+                     executor=executor, set="store")
 
 
 @register_executor("fused-scan")
@@ -767,7 +812,13 @@ def _exec_fused_scan(store, pruner, Q, spec, *, ivf, mesh, stats):
     and crowd out the k-th distinct neighbour) and its candidates merge
     exactly, unpruned — a hypothesis-test casualty there is impossible.
     Pruners other than ADSampling scan unpruned (thr = inf): they get the
-    bandwidth win without a foreign predicate."""
+    bandwidth win without a foreign predicate.
+
+    With ``spec.nprobe`` and an IVF index the query is routed on the
+    device: the lanes of every partition outside its ``nprobe`` nearest
+    buckets are killed, START is the nearest probed non-empty bucket's
+    first partition, and the prefetch-skip megakernel fetches nothing of
+    the dead partitions."""
     if spec.metric != "l2":
         raise ValueError(
             "fused-scan is L2-only (ADSampling's domain); the planner "
@@ -780,28 +831,46 @@ def _exec_fused_scan(store, pruner, Q, spec, *, ivf, mesh, stats):
     eps0 = float(pruner.aux["eps0"]) if prune else 2.1
     sc = mirror.scale if mirror.quantized else None
     off = mirror.offset if mirror.quantized else None
+    routed = ivf is not None and spec.nprobe is not None
     out_i, out_d = [], []
     for q in Q:
         qt = pruner.transform_query(jnp.asarray(q, jnp.float32))
-        p0 = 0
-        if ivf is not None:
-            order, _ = ivf.route(qt, 1, "l2", dtype=spec.route_dtype)
-            if len(order):
-                p0 = int(order[0])
-        start = topk_from_batch(
-            pdx_distance(store.data[p0], qt, "l2"), store.ids[p0], spec.k
-        )
+        probe = None
+        if routed:
+            with _trace.span("route", nprobe=spec.nprobe):
+                mask, first = ivf.probe_mask(
+                    qt, spec.nprobe, "l2", spec.route_dtype
+                )
+            probe, p0, start = _probed_start(
+                store.data, store.ids, mask, first, qt, spec.k
+            )
+        else:
+            p0 = 0
+            if ivf is not None:
+                order, _ = ivf.route(qt, 1, "l2", dtype=spec.route_dtype)
+                if len(order):
+                    p0 = int(order[0])
+            start = topk_from_batch(
+                pdx_distance(store.data[p0], qt, "l2"), store.ids[p0],
+                spec.k,
+            )
         thr = topk_threshold(start) if prune else jnp.float32(np.inf)
         res = _fused_scan_one(
             mirror.data, store.data, store.ids, jnp.int32(p0), qt, thr,
             sc, off, eps0, rk, spec.k, use_pallas,
             spec.scan_dtype == "f32", start,
-            packed=mirror.packed, dim=mirror.dim,
+            packed=mirror.packed, dim=mirror.dim, probe=probe,
         )
-        if stats is not None:
-            _fused_scan_stats(stats, store, mirror, p0, qt, thr, eps0)
         out_i.append(np.asarray(res.ids))
         out_d.append(np.asarray(res.dists))
+        counting = stats is not None or _metrics.enabled()
+        parts = np.asarray(probe) if probe is not None and counting else None
+        if stats is not None:
+            _fused_scan_stats(stats, store, mirror, int(p0), qt, thr, eps0,
+                              parts)
+        if parts is not None and _metrics.enabled():
+            _count_probe(int(parts.sum()), store.num_partitions,
+                         "fused-scan")
     if spec.scan_dtype != "f32":
         # the exact re-rank runs fused inside _fused_scan_one — record it
         # as a zero-width annotation span plus its gather bytes
@@ -815,33 +884,54 @@ def _exec_fused_scan(store, pruner, Q, spec, *, ivf, mesh, stats):
     return np.stack(out_i), np.stack(out_d)
 
 
-def _fused_scan_stats(stats, store, mirror, p0, qt, thr, eps0) -> None:
+@functools.partial(jax.jit, static_argnames=("k",))
+def _probed_start(master, ids, mask, first, qt, k):
+    """A routed query's (P,) probed partitions, its START partition and
+    START's exact top-``k``, from ``IVFIndex.probe_mask`` of a batch of
+    one.  With no probed non-empty bucket (``first`` -1) START is
+    partition 0 with every lane killed: empty, thr = inf."""
+    mask, p0 = mask[0], jnp.maximum(first[0], 0)
+    live = jnp.where(mask[p0], ids[p0], -1)
+    return mask, p0, topk_from_batch(
+        pdx_distance(master[p0], qt, "l2"), live, k
+    )
+
+
+def _fused_scan_stats(stats, store, mirror, p0, qt, thr, eps0,
+                      parts=None) -> None:
     """Work accounting for the megakernel: replay the per-d-tile keep-mask
     walk (``obs.meters.fused_tile_counts``) to recover how many lanes each
     tile computed — an explicit second pass over the mirror, paid only when
     stats are requested (the fused kernel itself can't count without
     spilling its mask).  The START partition is masked out of the walk and
-    charged at full D, exactly mirroring the executor."""
+    charged at full D, exactly mirroring the executor; with ``parts`` (the
+    (P,) probed partitions) only those count."""
     from ..obs import meters as _meters
 
     counts = np.asarray(store.counts)
     P, C = mirror.data.shape[0], mirror.data.shape[2]
     D = mirror.dim  # logical D (packed int4 halves the stored axis)
-    ids_scan = store.ids.at[p0].set(-1)
-    lanes, parts = _meters.fused_tile_counts(
+    if parts is None:
+        parts_ = np.ones(P, bool)
+        ids_scan = store.ids.at[p0].set(-1)
+    else:
+        parts_ = parts
+        ids_scan = jnp.where(jnp.asarray(parts)[:, None], store.ids, -1)
+        ids_scan = ids_scan.at[p0].set(-1)
+    lanes, tiles = _meters.fused_tile_counts(
         mirror.data, ids_scan, qt, thr, mirror.scale, mirror.offset,
         eps0=eps0, packed=mirror.packed, dim=mirror.dim,
     )
     w = _meters.tile_widths(D)
-    total = float(counts.sum()) * D
-    computed = float(counts[p0]) * D + float((lanes * w).sum())
+    total = float(counts[parts_].sum()) * D
+    computed = float(counts[p0] * parts_[p0]) * D + float((lanes * w).sum())
     stats.values_total += total
     stats.values_computed += computed
     stats.values_avoided += total - computed
-    stats.partitions_visited += P
+    stats.partitions_visited += int(parts_.sum())
     if _metrics.enabled():
         demand = (
-            D * C * 4 + float((parts * w).sum()) * C * mirror.bytes_per_value
+            D * C * 4 + float((tiles * w).sum()) * C * mirror.bytes_per_value
         )
         _metrics.counter(
             "repro_device_bytes_total", demand,
@@ -857,14 +947,18 @@ def _fused_scan_stats(stats, store, mirror, p0, qt, thr, eps0) -> None:
 def _fused_scan_one(
     mdata, master, ids, p0, qt, thr, scale, offset, eps0, rk, k, use_pallas,
     exact, start: TopK, packed: bool = False, dim: int | None = None,
+    probe=None,
 ) -> TopK:
-    from ..kernels.ops import pdx_prune_scan_multi_op
+    from ..kernels.ops import pdx_prune_scan_multi_prefetch_op
 
     P, _, C = mdata.shape
-    # the START partition was scanned exactly already: kill its lanes so the
-    # megakernel whole-tile-skips it and its ids never enter the pool twice
-    ids_scan = ids.at[p0].set(-1)
-    dists, alive = pdx_prune_scan_multi_op(
+    # the START partition was scanned exactly already: kill its lanes so its
+    # ids never enter the pool twice; unprobed partitions (``probe``) are
+    # dead on entry too.  The prefetch schedule lists only live partitions
+    # and stops fetching a partition at the d-tile where its last lane dies
+    ids_scan = ids if probe is None else jnp.where(probe[:, None], ids, -1)
+    ids_scan = ids_scan.at[p0].set(-1)
+    dists, alive, _ = pdx_prune_scan_multi_prefetch_op(
         mdata, ids_scan, qt, thr, scale, offset, eps0=eps0,
         use_pallas=use_pallas, packed=packed, dim=dim,
     )

@@ -92,7 +92,12 @@ class SearchSpec:
       group      — partitions evaluated per pruning round (host path).
 
     IVF routing
-      nprobe     — buckets probed when the engine has an IVF index.
+      nprobe     — lists (buckets) probed per query when the engine has an
+                   IVF index, the same on every executor: None (default)
+                   probes every list, an integer exactly that many (the
+                   fused executors mask each query to its own lists and
+                   scan only the partitions some query of the batch
+                   probes).
       routing    — distributed query routing on a "data"-axis mesh:
                    "bucket" (default) routes each query only to the shards
                    owning its top-nprobe buckets (one all-to-all per batch,
@@ -166,7 +171,7 @@ class SearchSpec:
     delta_d: int = 32
     sel_frac: float = 0.2
     group: int = 8
-    nprobe: int = 8
+    nprobe: Optional[int] = None
     executor: Optional[str] = None
     prefer_static: bool = False
     batch_collectives: bool = True
@@ -193,8 +198,10 @@ class SearchSpec:
             raise ValueError(f"sel_frac must be in (0, 1], got {self.sel_frac}")
         if self.group < 1:
             raise ValueError(f"group must be >= 1, got {self.group}")
-        if self.nprobe < 1:
-            raise ValueError(f"nprobe must be >= 1, got {self.nprobe}")
+        if self.nprobe is not None and self.nprobe < 1:
+            raise ValueError(
+                f"nprobe must be >= 1 or None, got {self.nprobe}"
+            )
         if self.routing not in ROUTINGS:
             raise ValueError(
                 f"routing must be one of {ROUTINGS}, got {self.routing!r}"

@@ -144,10 +144,11 @@ def slot_topk_init(B: int, C: int, R: int) -> SlotTopK:
 
 def slot_topk_insert(state: SlotTopK, dists: jax.Array, p, pad: jax.Array
                      ) -> SlotTopK:
-    """Insert partition ``p``'s (B, C) distances; ``pad`` (C,) marks PAD lanes,
-    which enter as +inf and displace nothing.  The new entry goes in after
-    every kept entry it does not beat strictly (those came from lower
-    partitions, so they win ties) and the levels below it shift down one."""
+    """Insert partition ``p``'s (B, C) distances; ``pad`` (C,) or (B, C)
+    marks PAD lanes (and entries a query skips), which enter as +inf and
+    displace nothing.  The new entry goes in after every kept entry it does
+    not beat strictly (those came from lower partitions, so they win ties)
+    and the levels below it shift down one."""
     x = jnp.where(pad, _INF_KEY, _f32_key(dists))
     keys, parts = [], []
     above = None  # (took, key, part) of the level above
@@ -203,45 +204,84 @@ def slot_topk_finish(state: SlotTopK, rk: int) -> tuple[TopK, jax.Array]:
     return TopK(dists=_key_f32(out_k), ids=out_p), fallback
 
 
-def scan_topk(distances, xs, pad: jax.Array, B: int, rk: int):
-    """Per-query running top-``rk`` flat positions over a ``lax.scan`` of P
-    tiles: ``distances(x)`` gives the (B, C) distances of each leading
+def scan_topk(distances, xs, pad: jax.Array, B: int, rk: int, probe=None):
+    """Per-query running top-``rk`` flat positions over the P tiles of
+    ``xs``: ``distances(x)`` gives the (B, C) distances of each leading
     slice of ``xs``; ``pad`` (P, C) marks PAD lanes (position -1).
 
     Keeps the slot-wise top-R of ``slot_topk_levels`` and selects once;
     when the certificate fails, the same program re-runs the scan with the
     per-step ``topk_merge``, so the answer is always the merge's, bit for
     bit.  Returns (TopK of (B, rk), fallback flag); the flag is None where
-    the shapes keep the merge alone."""
+    the shapes keep the merge alone.
+
+    ``probe`` (B, P) bool, when given, restricts each query to its own
+    partitions: its distances on the others enter as +inf, as PAD lanes
+    do, and only the partitions some query probes are read, in ascending
+    order, by a loop whose trip count is their number.  The answer is the
+    full scan's with the unprobed entries at +inf, bit for bit."""
     P, C = pad.shape
     pos = jnp.where(pad, -1, jnp.arange(P * C, dtype=jnp.int32).reshape(P, C))
+    if probe is None:
+        # a divisor of P: with a remainder, lax.scan copies the other tiles
+        unroll = max(u for u in range(1, _SLOT_UNROLL + 1) if P % u == 0)
+
+        def walk(insert, init):
+            def body(state, inp):
+                x, p, tpad = inp
+                return insert(state, distances(x), p, tpad), None
+
+            return jax.lax.scan(
+                body, init, (xs, jnp.arange(P, dtype=jnp.int32), pad),
+                unroll=unroll,
+            )[0]
+    else:
+        walk = _probed_walk(distances, xs, pad, probe)
+
+    def merge_insert(state: TopK, d, p, skip) -> TopK:
+        ids = jnp.where(skip, -1, pos[p])
+        return jax.vmap(topk_merge)(state, d, jnp.broadcast_to(ids, d.shape))
 
     def merge_scan(_=None) -> TopK:
-        def body(state: TopK, inp):
-            x, tpos = inp
-            return jax.vmap(topk_merge, (0, 0, None))(
-                state, distances(x), tpos), None
-
         init = jax.vmap(lambda _: topk_init(rk))(jnp.arange(B))
-        return jax.lax.scan(body, init, (xs, pos))[0]
+        return walk(merge_insert, init)
 
     R = slot_topk_levels(B, C, rk)
     if R is None:
         return merge_scan(), None
-
-    def body(state: SlotTopK, inp):
-        x, p, tpad = inp
-        return slot_topk_insert(state, distances(x), p, tpad), None
-
-    # a divisor of P: with a remainder, lax.scan copies the other tiles out
-    unroll = max(u for u in range(1, _SLOT_UNROLL + 1) if P % u == 0)
-    slots, _ = jax.lax.scan(
-        body, slot_topk_init(B, C, R),
-        (xs, jnp.arange(P, dtype=jnp.int32), pad), unroll=unroll,
-    )
+    slots = walk(slot_topk_insert, slot_topk_init(B, C, R))
     cand, fallback = slot_topk_finish(slots, rk)
     cand = jax.lax.cond(fallback, merge_scan, lambda c: c, cand)
     return cand, fallback
+
+
+def _probed_walk(distances, xs, pad, probe):
+    """``scan_topk``'s walk over the union of the probed partitions: their
+    ids packed in ascending order, ``_SLOT_UNROLL`` to a step, a trip count
+    of whole steps; the places past the union read partition 0, skipped
+    by every query.  One program serves every union size."""
+    P, _ = pad.shape
+    U = min(_SLOT_UNROLL, P)
+    cap = -(-P // U) * U
+    union = jnp.any(probe, axis=0)
+    n = jnp.sum(union, dtype=jnp.int32)
+    plist = jnp.zeros((cap,), jnp.int32).at[
+        jnp.where(union, jnp.cumsum(union) - 1, cap)
+    ].set(jnp.arange(P, dtype=jnp.int32), mode="drop")
+
+    def walk(insert, init):
+        def step(i, state):
+            for j in range(U):
+                p = plist[i * U + j]
+                live = probe[:, p] & (i * U + j < n)
+                x = jax.lax.dynamic_index_in_dim(xs, p, keepdims=False)
+                state = insert(state, distances(x), p,
+                               pad[p][None, :] | ~live[:, None])
+            return state
+
+        return jax.lax.fori_loop(0, (n + U - 1) // U, step, init)
+
+    return walk
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))

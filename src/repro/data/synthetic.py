@@ -7,7 +7,8 @@ from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["make_dataset", "ground_truth", "recall_at_k", "DATASET_KINDS"]
+__all__ = ["make_dataset", "ground_truth", "ivf_probe_ground_truth",
+           "recall_at_k", "DATASET_KINDS"]
 
 DATASET_KINDS = ("normal", "skewed", "clustered")
 
@@ -103,6 +104,30 @@ def ground_truth(
                 best_d, best_i = alld[sel], alli[sel]
         order = np.argsort(best_d, kind="stable")
         ids[qi], ds[qi] = best_i[order], best_d[order]
+    return ids, ds
+
+
+def ivf_probe_ground_truth(
+    X: np.ndarray, Q: np.ndarray, centroids: np.ndarray, assign: np.ndarray,
+    nprobe: int, k: int,
+) -> tuple[np.ndarray, np.ndarray]:
+    """Exact top-k under L2 among the rows of each query's ``nprobe``
+    nearest IVF lists, in float64: (B, k) ids and dists (-1 / inf where
+    the lists hold fewer than k rows).  The index is data here: its
+    ``centroids`` (K, D) and each row's list ``assign`` (n,); the lists are
+    ranked by exact distance to the centroids, ties to the lower list."""
+    X = np.asarray(X, np.float64)
+    C = np.asarray(centroids, np.float64)
+    assign = np.asarray(assign)
+    B = len(Q)
+    ids = np.full((B, k), -1, np.int64)
+    ds = np.full((B, k), np.inf)
+    for qi, q in enumerate(np.asarray(Q, np.float64)):
+        lists = np.argsort(((C - q) ** 2).sum(1), kind="stable")[:nprobe]
+        rows = np.flatnonzero(np.isin(assign, lists))
+        d = ((X[rows] - q) ** 2).sum(1)
+        best = np.argsort(d, kind="stable")[:k]
+        ids[qi, : len(best)], ds[qi, : len(best)] = rows[best], d[best]
     return ids, ds
 
 

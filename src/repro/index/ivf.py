@@ -125,6 +125,25 @@ def _rank_centroids_tree(
     return jax.vmap(one)(Q)
 
 
+@functools.partial(jax.jit, static_argnames=("nprobe",))
+def _probe_mask(order, part_bucket, offsets, counts, nprobe: int):
+    """(B, nlist) bucket orders (-1 right-pads) -> the (B, P) mask of the
+    partitions of each query's first ``nprobe`` buckets, and (B,) the first
+    partition of its nearest probed non-empty bucket (-1: none).
+    ``part_bucket`` (P,) names each partition's bucket (-1: none)."""
+    B, nlist = order.shape
+    sel = order[:, :nprobe]
+    ok = sel >= 0
+    hit = jnp.zeros((B, nlist), bool).at[
+        jnp.arange(B)[:, None], jnp.where(ok, sel, nlist)
+    ].set(True, mode="drop")
+    mask = hit[:, jnp.maximum(part_bucket, 0)] & (part_bucket >= 0)[None, :]
+    full = ok & (counts[jnp.maximum(sel, 0)] > 0)
+    b0 = jnp.take_along_axis(sel, jnp.argmax(full, axis=1)[:, None], 1)[:, 0]
+    start = jnp.where(jnp.any(full, axis=1), offsets[jnp.maximum(b0, 0)], -1)
+    return mask, start.astype(jnp.int32)
+
+
 @jax.jit
 def _nearest_centroid(centroids: jax.Array, X: jax.Array) -> jax.Array:
     """(K, D), (N, D) -> (N,) nearest-centroid bucket per row (L2, matching
@@ -149,6 +168,10 @@ class IVFIndex:
     super_centroids: Optional[jax.Array] = None
     super_children: Optional[jax.Array] = None
     nprobe_super: int = 0
+    # device (part_bucket, offsets, counts) and the host arrays they copy
+    _dev_buckets: Optional[tuple] = dataclasses.field(
+        default=None, repr=False, compare=False
+    )
 
     @property
     def tree_enabled(self) -> bool:
@@ -233,6 +256,44 @@ class IVFIndex:
             )
         return order
 
+    def probes(self, nprobe: Optional[int]) -> int:
+        """Buckets a query probes at ``SearchSpec.nprobe``: every one for
+        None, else at most ``nprobe``."""
+        return self.nlist if nprobe is None else min(int(nprobe), self.nlist)
+
+    def _bucket_arrays(self) -> tuple:
+        """Device copies of the bucket -> partition map, refreshed when the
+        engine hands the index new ``part_offsets`` / ``part_counts``."""
+        P = self.store.num_partitions
+        cached = self._dev_buckets
+        if (cached is None or cached[0] is not self.part_offsets
+                or cached[1] is not self.part_counts or cached[2] != P):
+            counts = np.asarray(self.part_counts, np.int64)
+            pb = np.full(P, -1, np.int32)
+            for b, (o, c) in enumerate(zip(self.part_offsets, counts)):
+                pb[o:o + c] = b
+            dev = (jnp.asarray(pb), jnp.asarray(self.part_offsets, jnp.int32),
+                   jnp.asarray(counts, jnp.int32))
+            cached = (self.part_offsets, self.part_counts, P, dev)
+            self._dev_buckets = cached
+        return cached[3]
+
+    def probe_mask(
+        self, Qt: jax.Array, nprobe: int, metric: str = "l2",
+        dtype: str = "f32",
+    ) -> tuple[jax.Array, jax.Array]:
+        """Device-side routing of a (B, D) batch of (already pruner-
+        transformed) queries: the (B, P) bool mask of the partitions of
+        each query's ``nprobe`` nearest buckets, and (B,) int32 the first
+        partition of its nearest probed non-empty bucket (-1: none).  The
+        centroids are ranked at ``dtype`` as ``route_batch`` ranks them;
+        nothing is read back to the host."""
+        order = self._ranked_batch(
+            jnp.atleast_2d(jnp.asarray(Qt, jnp.float32)), metric, dtype
+        )
+        return _probe_mask(order, *self._bucket_arrays(),
+                           nprobe=self.probes(nprobe))
+
     def rank_buckets(
         self, q: jax.Array, metric: str = "l2", dtype: str = "f32"
     ) -> np.ndarray:
@@ -251,8 +312,10 @@ class IVFIndex:
         X = jnp.atleast_2d(jnp.asarray(X, jnp.float32))
         return np.asarray(_nearest_centroid(self.centroids, X))
 
-    def partition_order(self, bucket_order: np.ndarray, nprobe: int) -> np.ndarray:
-        sel = bucket_order[:nprobe]
+    def partition_order(
+        self, bucket_order: np.ndarray, nprobe: Optional[int]
+    ) -> np.ndarray:
+        sel = bucket_order[: self.probes(nprobe)]
         parts = [
             np.arange(
                 self.part_offsets[b], self.part_offsets[b] + self.part_counts[b]
@@ -263,22 +326,22 @@ class IVFIndex:
         return np.concatenate(parts) if parts else np.zeros(0, np.int64)
 
     def route_batch(
-        self, Qt: jax.Array, nprobe: int, metric: str = "l2",
+        self, Qt: jax.Array, nprobe: Optional[int], metric: str = "l2",
         dtype: str = "f32",
     ) -> np.ndarray:
         """Query routing for the distributed bucket-routed executor: rank
         buckets for a whole (B, D) batch of (already pruner-transformed)
-        queries -> (B, min(nprobe, nlist)) bucket ids, best first.  The
+        queries -> (B, probes(nprobe)) bucket ids, best first.  The
         caller (``repro.dist.routing``) maps buckets onto owner shards via
         the placement and exchanges queries with one all-to-all.  ``dtype``
         runs the centroid scan over a quantized mirror (host-side, pre-
         collective: the exchange plan and collective count are unchanged)."""
         Qt = jnp.atleast_2d(jnp.asarray(Qt, jnp.float32))
         order = self._ranked_batch(Qt, metric, dtype)
-        return np.asarray(order[:, : min(nprobe, self.nlist)])
+        return np.asarray(order[:, : self.probes(nprobe)])
 
     def route(
-        self, qt: jax.Array, nprobe: int, metric: str = "l2",
+        self, qt: jax.Array, nprobe: Optional[int], metric: str = "l2",
         dtype: str = "f32",
     ) -> tuple[np.ndarray, int]:
         """Query routing for the planner's adaptive executor: rank buckets
@@ -289,7 +352,7 @@ class IVFIndex:
         border = self.rank_buckets(qt, metric, dtype)
         order = self.partition_order(border, nprobe)
         start_parts = 0
-        for b in border[:nprobe]:
+        for b in border[: self.probes(nprobe)]:
             if b >= 0 and self.part_counts[b] > 0:
                 start_parts = int(self.part_counts[b])
                 break

@@ -63,6 +63,17 @@ families:
     repro_collectives_per_call{executor,primitive}
                                                 gauge, counted in the jaxpr at
                                                 compile time (obs.meters)
+    repro_ivf_probe_partitions_total{executor,set}
+                                                set=scanned|store: partitions
+                                                a routed fused batch read
+                                                (the union of its queries'
+                                                probed buckets) beside the
+                                                store's, per batch, when
+                                                SearchSpec.nprobe is stated
+    repro_slot_topk_batches_total{outcome}      outcome=certified|fallback:
+                                                fused-batch batches whose
+                                                slot-wise top-k certified or
+                                                re-ran the per-step merge
     repro_device_bytes_total{executor,component,dtype}
                                                 component=scan|rerank|
                                                 all_to_all|all_gather|
@@ -100,7 +111,9 @@ the whole call); phases nest under it:
     plan    planner dispatch (``core.plan.plan_search``)
     route   IVF bucket ranking + exchange planning (adaptive per-query
             routing, or ``route_batch``/``plan_routing``/send-buffer packing
-            on the routed path)
+            on the routed path); in the fused executors, with
+            ``SearchSpec.nprobe`` stated, the device ranking and probe
+            mask of ``IVFIndex.probe_mask`` (dispatch, not fenced)
     scan    executor body — device work fenced by ``block_until_ready``
             (every executor returns host arrays, so the span wall includes
             device completion)

@@ -404,7 +404,7 @@ class VectorServer:
             self.shed_depth is not None
             and self.engine.ivf is not None
             and len(self._queue) >= self.shed_depth
-            and spec.nprobe > self.shed_nprobe
+            and (spec.nprobe is None or spec.nprobe > self.shed_nprobe)
         ):
             spec = spec.replace(nprobe=self.shed_nprobe)
             shed = True

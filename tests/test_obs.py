@@ -177,7 +177,7 @@ def test_engine_metrics_trace_and_stats_parity(obs, tmp_path):
         X, index="ivf", pruner="adsampling", capacity=128, nlist=16,
     )
     stats = SearchStats()
-    res = eng.search(Q[0], SearchSpec(k=5), stats=stats)
+    res = eng.search(Q[0], SearchSpec(k=5, nprobe=8), stats=stats)
     assert res.plan.executor == "adaptive"
     qt = res.trace
     assert qt is not None and qt.attrs["executor"] == "adaptive"
@@ -224,7 +224,7 @@ def test_stats_populated_on_every_single_device_executor(obs):
     # exact=False paths account only what they visit/compute
     flat = VectorSearchEngine.build(X, pruner="adsampling", capacity=128)
     cases = [
-        (eng, "adaptive", SearchSpec(k=5), Q[0], False),
+        (eng, "adaptive", SearchSpec(k=5, nprobe=8), Q[0], False),
         (flat, "batch-matmul", SearchSpec(k=5), Q, True),
         (eng, "fused-scan", SearchSpec(k=5, scan_dtype="int8", kernel="jnp",
                                        executor="fused-scan"), Q[0], False),

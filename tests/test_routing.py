@@ -256,7 +256,7 @@ def test_routed_bucket_matches_single_host_ivf_8dev():
     r1 = eng.search(Q[0], SearchSpec(k=5, nprobe=nlist))
     assert r1.plan.executor == "routed_bucket"
     assert set(r1.ids.tolist()) == set(gt_ids[0].tolist())
-    rb = eng.search(Q, SearchSpec(k=5, routing="broadcast"))
+    rb = eng.search(Q, SearchSpec(k=5, nprobe=8, routing="broadcast"))
     assert rb.plan.executor == "adaptive", rb.plan
     print("OK")
     """)

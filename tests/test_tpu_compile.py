@@ -161,3 +161,25 @@ def test_fused_batch_scan_compiles(compiled, D):
         compiled((B, D), jnp.float32),
         scale, offset,
     )
+
+
+@pytest.mark.parametrize("D", [1536, 960])
+def test_fused_batch_scan_probed_compiles(compiled, D):
+    """The routed fused-batch program: each query masked to its probed
+    partitions, the union walked by a loop of data-dependent length."""
+    from repro.core.plan import _fused_batch_scan
+
+    def scan(mdata, ids, Qt, scale, offset, probe):
+        return _fused_batch_scan(mdata, ids, Qt, scale, offset, rk=40,
+                                 metric="l2", use_pallas=True,
+                                 quantized=True, probe=probe)
+
+    scale, offset = _dequant(compiled, D, True)
+    _assert_kernel_compiles(
+        scan,
+        compiled((P_PARTS, D, C), jnp.int8),
+        compiled((P_PARTS, C), jnp.int32),
+        compiled((B, D), jnp.float32),
+        scale, offset,
+        compiled((B, P_PARTS), jnp.bool_),
+    )
